@@ -90,6 +90,9 @@ def write_summary(rows, gm_pos, gm_all, ubench_us, serving=None, path="BENCH_air
 def main() -> None:
     fast = "--fast" in sys.argv
     from benchmarks import fig12_granularity, fig34_aira, roofline, serving_load
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     fig12_granularity.run()
     print()
@@ -125,10 +128,20 @@ def main() -> None:
     # mesh-sharded paged decode at mesh sizes {1,2,4} on one workload:
     # bitwise token identity vs the single-device paged path (plain,
     # speculative, chunked) plus per-step latency per mesh size — the
-    # tracked tensor-parallel serving contract (DESIGN.md §5). Runs in
-    # a forced multi-device CPU subprocess when this process has one
-    # real device (the normal CI case).
-    serving["sharded"] = serving_load.run_sharded()
+    # tracked tensor-parallel serving contract (DESIGN.md §5). On a CPU
+    # host with one device it runs in a forced multi-device CPU
+    # subprocess (the normal CI case); an accelerator host runs it
+    # in-process when it has the devices, and otherwise records why it
+    # did not run — never a child that would need the parent's chip.
+    need = serving_load.sharded_devices_needed()
+    if jax.default_backend() == "cpu" or len(jax.devices()) >= need:
+        serving["sharded"] = serving_load.run_sharded()
+    else:
+        serving["sharded"] = {
+            "not_run": f"needs {need} devices, this "
+                       f"{jax.default_backend()} host has {len(jax.devices())}"
+        }
+        print(f"§Serving sharded: not run — {serving['sharded']['not_run']}")
     print()
     # flight-recorder contract: telemetry-on serves the same workload
     # token-identically through the same warmed engine, the paired-rep

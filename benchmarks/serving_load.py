@@ -61,11 +61,12 @@ is asserted for the head-only sizes, for plain, speculative (K=2), and
 chunked-prefill serving — head partitioning moves parallel work, never
 a reduction order — while the seq lanes assert argmax token identity
 (the exact-combine tolerance contract); per-decode-step
-latency is recorded per mesh size. When the process has fewer devices
-than the largest mesh (the normal single-device CI run), the sweep
-re-execs itself in a subprocess with a forced multi-device CPU host
-platform, so ``benchmarks.run`` still lands ``serving.sharded`` in the
-summary.
+latency is recorded per mesh size. When a CPU process has fewer
+devices than the largest mesh (the normal single-device CI run), the
+sweep re-execs itself in a CPU-pinned subprocess with a forced
+multi-device host platform, so ``benchmarks.run`` still lands
+``serving.sharded`` in the summary. On an accelerator it runs
+in-process or refuses, naming the device count it needs.
 
 The drift mode (``run_drift`` / ``--drift``) is the online-adviser
 proof (DESIGN.md §9): a phased workload whose draftability drifts
@@ -724,13 +725,16 @@ def _run_sharded_subprocess(kwargs: dict, need: int, print_fn) -> dict:
     platform. XLA_FLAGS must be set before jax initializes, and this
     process has already initialized it with its real (single) device —
     so the sweep itself runs in a child and ships its summary back as a
-    sentinel-prefixed JSON line."""
+    sentinel-prefixed JSON line. CPU only: the child is pinned to the
+    CPU platform, and the caller never takes this path on an
+    accelerator (the parent would hold the chip the child asks for)."""
     import json
     import os
     import subprocess
     import sys
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={need}"
@@ -757,6 +761,14 @@ def _run_sharded_subprocess(kwargs: dict, need: int, print_fn) -> dict:
             print_fn(line)
     assert summary is not None, r.stdout
     return summary
+
+
+def sharded_devices_needed(mesh_sizes=(1, 2, 4), seq_shapes=((2,), (2, 2))) -> int:
+    """Devices the largest mesh of a ``run_sharded`` sweep spans."""
+    return max(
+        max(mesh_sizes),
+        max((int(np.prod(s)) for s in seq_shapes), default=1),
+    )
 
 
 def run_sharded(
@@ -796,11 +808,15 @@ def run_sharded(
     dispatch/collective overhead, not speedup — the contract asserted
     here is identity, the latency is reported."""
     seq_shapes = tuple(tuple(s) for s in seq_shapes)
-    need = max(
-        max(mesh_sizes),
-        max((int(np.prod(s)) for s in seq_shapes), default=1),
-    )
+    need = sharded_devices_needed(mesh_sizes, seq_shapes)
     if need > 1 and len(jax.devices()) < need:
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"the sharded sweep needs {need} devices; this "
+                f"{jax.default_backend()} host has {len(jax.devices())}. "
+                "Run it on a host with that many chips (it runs in-process "
+                "there), or on a CPU host, where it forces host devices"
+            )
         return _run_sharded_subprocess(
             dict(arch=arch, n_requests=n_requests, rate_rps=rate_rps,
                  max_batch=max_batch, tokens=tokens,
@@ -837,12 +853,9 @@ def run_sharded(
     results, outputs, base_raw = {}, {}, {}
     for tp in mesh_sizes:
         if tp > 1:
-            try:
-                mesh = jax.make_mesh(
-                    (tp,), ("model",), axis_types=(jax.sharding.AxisType.Auto,)
-                )
-            except AttributeError:  # jax 0.4.x: no AxisType
-                mesh = jax.make_mesh((tp,), ("model",))
+            mesh = jax.make_mesh(
+                (tp,), ("model",), axis_types=(jax.sharding.AxisType.Auto,)
+            )
         else:
             mesh = None
         engine = ServingEngine(
@@ -886,12 +899,9 @@ def run_sharded(
     for shape in seq_shapes:
         names = ("seq",) if len(shape) == 1 else ("model", "seq")
         key = "x".join(f"{n}{s}" for n, s in zip(names, shape))
-        try:
-            mesh = jax.make_mesh(
-                shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(shape)
-            )
-        except AttributeError:  # jax 0.4.x: no AxisType
-            mesh = jax.make_mesh(shape, names)
+        mesh = jax.make_mesh(
+            shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(shape)
+        )
         engine = ServingEngine(
             model, params, max_seq=64, kv_layout="paged", mesh=mesh,
             attention_backend=backend,
@@ -1306,6 +1316,9 @@ if __name__ == "__main__":
                          "(argmax token identity, the kv-sequence-split "
                          "tolerance lane; CI smoke: --mesh 2x2)")
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
     if args.shared_prefix:
         run_shared_prefix()
     elif args.speculative:

@@ -55,8 +55,11 @@ token streams are unchanged (the observability benchmark pins this).
 N-way ``("model",)`` mesh and decode/verify run per-shard under
 ``shard_map`` — token streams are bitwise those of the single-device
 paged path. Requires ``--paged`` and ``--open-loop`` (the mesh is wired
-through ``engine.serve(mesh=)``); on a single-device CPU host the
-script forces an N-device host platform for you. Architectures whose
+through ``engine.serve(mesh=)``); on a CPU host the script forces an
+N-device host platform for you (that flag acts on the CPU platform
+only; an accelerator host serves on its real devices). The first line
+names the platform and device count the run resolved, so a chip run
+can be told from a CPU one. Architectures whose
 kv-head count the mesh does not divide fall back to replicated serving
 with a logged warning.
 
@@ -70,8 +73,10 @@ import dataclasses
 import os
 import sys
 
-# --mesh on a single-device CPU host needs the forced device count set
-# BEFORE jax initializes, so peek at argv ahead of the jax import
+# --mesh on a CPU host needs the forced device count set BEFORE jax
+# initializes, so peek at argv ahead of the jax import. The flag acts
+# on the CPU host platform only; a TPU host ignores it and meshes its
+# real chips
 if "--mesh" in sys.argv[:-1]:
     _n = int(sys.argv[sys.argv.index("--mesh") + 1])
     _flags = os.environ.get("XLA_FLAGS", "")
@@ -133,6 +138,10 @@ def main():
                          "Chrome/Perfetto trace-event JSON to PATH "
                          "(DESIGN.md §8; load in ui.perfetto.dev)")
     args = ap.parse_args()
+    print(
+        f"platform={jax.default_backend()} devices={len(jax.devices())} "
+        f"kind={jax.devices()[0].device_kind}"
+    )
 
     if args.trace:
         from repro.serve.telemetry import configure
@@ -163,13 +172,9 @@ def main():
                 f"xla_force_host_platform_device_count for you — is "
                 f"XLA_FLAGS already pinning a smaller count?)"
             )
-        try:
-            mesh = jax.make_mesh(
-                (args.mesh,), ("model",),
-                axis_types=(jax.sharding.AxisType.Auto,),
-            )
-        except AttributeError:  # jax 0.4.x: no AxisType
-            mesh = jax.make_mesh((args.mesh,), ("model",))
+        mesh = jax.make_mesh(
+            (args.mesh,), ("model",), axis_types=(jax.sharding.AxisType.Auto,)
+        )
     model = Model(cfg)
     params, _ = model.init(jax.random.key(0))
     from repro.serve import SpecConfig

@@ -85,14 +85,13 @@ class Benchmark:
         comparable to serial_value). Under an outer trace the plan cache
         is bypassed — caching a closure over tracers would leak them.
         """
-        from repro import compat
         from repro.core import plan as plan_mod
         from repro.core.relic import relic_pfor
 
         fn = self.item_fn(data)
         its = self.items(data)
         comb = combine or "stack"
-        if any(compat.is_tracer(l) for l in jax.tree.leaves((its, data))):
+        if any(isinstance(l, jax.core.Tracer) for l in jax.tree.leaves((its, data))):
             return relic_pfor(fn, its, granularity=granularity, combine=comb)
         plan = plan_mod.plan_for(
             self.name,

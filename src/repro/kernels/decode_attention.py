@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG = -1e30
 
@@ -103,7 +102,7 @@ def decode_attention(
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

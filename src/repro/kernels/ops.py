@@ -187,6 +187,28 @@ def check_vmem(block_bytes: dict[str, int], buffering: int = 2) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _paged_blocks(q, k_pool, v_pool, k_scale) -> dict[str, int]:
+    """VMEM bytes per buffer of the paged kernel's block schedule: one
+    whole pool block ``[BS, KV, hd]`` per K/V step (every kv group), the
+    ``[KV, T·g, hd]`` query block, output block (counted at f32, its
+    partials-mode dtype) and f32 accumulator, and the per-group
+    ``[T·g, BS]`` score tile."""
+    T, H, hd = q.shape[1], q.shape[2], q.shape[3]
+    BS, KV = k_pool.shape[1], k_pool.shape[2]
+    rows = T * (H // KV)
+    blocks = {
+        "q": KV * rows * hd * jnp.dtype(q.dtype).itemsize,
+        "o": KV * rows * hd * 4,
+        "k": BS * KV * hd * jnp.dtype(k_pool.dtype).itemsize,
+        "v": BS * KV * hd * jnp.dtype(v_pool.dtype).itemsize,
+        "acc": KV * rows * hd * 4,
+        "s": rows * BS * 4,
+    }
+    if k_scale is not None:
+        blocks["scales"] = 2 * BS * KV * jnp.dtype(k_scale.dtype).itemsize
+    return blocks
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "mode"))
 def matmul(x, w, *, bm=256, bk=512, bn=256, mode="auto"):
     """Double-buffered block matmul (Relic pair-scheduling on one core)."""
@@ -296,19 +318,7 @@ def paged_attention_partials(
             "paged_attention_partials is the kernel-backend surface; the "
             "reference partials are models/attention.paged_flash_partials"
         )
-    itemsize = jnp.dtype(q.dtype).itemsize
-    T, hd = q.shape[1], q.shape[3]
-    BS = k_pool.shape[1]
-    g = q.shape[2] // k_pool.shape[2]
-    check_vmem(
-        {
-            "q": T * g * hd * itemsize,
-            "k": BS * hd * jnp.dtype(k_pool.dtype).itemsize,
-            "v": BS * hd * jnp.dtype(v_pool.dtype).itemsize,
-            "acc": T * g * hd * 4,
-            "s": T * g * BS * 4,
-        }
-    )
+    check_vmem(_paged_blocks(q, k_pool, v_pool, k_scale))
     return _paged_kernel(
         q, k_pool, v_pool, block_tables, lengths,
         k_scale=k_scale, v_scale=v_scale, owned=owned, partials=True,
@@ -324,19 +334,7 @@ def _paged_attention_impl(
         return ref_ops.paged_attention_ref(
             q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale
         )
-    itemsize = jnp.dtype(q.dtype).itemsize
-    T, hd = q.shape[1], q.shape[3]
-    BS = k_pool.shape[1]
-    g = q.shape[2] // k_pool.shape[2]
-    check_vmem(
-        {
-            "q": T * g * hd * itemsize,
-            "k": BS * hd * jnp.dtype(k_pool.dtype).itemsize,
-            "v": BS * hd * jnp.dtype(v_pool.dtype).itemsize,
-            "acc": T * g * hd * 4,
-            "s": T * g * BS * 4,
-        }
-    )
+    check_vmem(_paged_blocks(q, k_pool, v_pool, k_scale))
     return _paged_kernel(
         q, k_pool, v_pool, block_tables, lengths,
         k_scale=k_scale, v_scale=v_scale, interpret=mode == "interpret",

@@ -10,7 +10,10 @@ thread" streams exactly the blocks the row owns while the "compute
 thread" runs the running-max softmax in VMEM — the same SMT-pair
 co-scheduling as ``decode_attention``, now addressed through pages.
 
-Grid ``(B, KV, MB)``: the sequential block-table axis is innermost;
+Grid ``(B, MB)``: the sequential block-table axis is innermost. Each
+grid step DMAs one whole pool block ``[BS, KV, hd]`` — all kv groups at
+once, so the block's trailing dims are the pool's own and the TPU
+compiler accepts the tiling — and loops over the kv groups in the body;
 the T·g query rows of each kv group ride in the sublane dim. T is
 static — T=1 is plain decode, T=K+1 the speculative verify (query t
 attends positions < len + t + 1, so the masked reduction per query is
@@ -31,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG = -1e30
 
@@ -39,26 +41,27 @@ NEG = -1e30
 def _paged_kernel(
     tbl_ref,  # [B, MB] int32 (scalar prefetch)
     len_ref,  # [B] int32 (scalar prefetch)
-    q_ref,  # [1, 1, T·g, hd]
-    k_ref,  # [1, BS, 1, hd]
+    q_ref,  # [1, KV, T·g, hd]
+    k_ref,  # [1, BS, KV, hd] — one pool block, every kv group
     v_ref,
-    o_ref,  # [1, 1, T·g, hd]
-    m_ref,  # [T·g, 1] f32 scratch
+    o_ref,  # [1, KV, T·g, hd]
+    m_ref,  # [KV, T·g, 1] f32 scratch
     l_ref,
-    acc_ref,  # [T·g, hd] f32 scratch
+    acc_ref,  # [KV, T·g, hd] f32 scratch
     *,
     scale,
     bs,
     t,
     g,
-    ks_ref=None,  # [1, BS, 1] per-vector scales (int8 pools)
+    kv,
+    ks_ref=None,  # [1, BS, KV] per-vector scales (int8 pools)
     vs_ref=None,
     own_ref=None,  # [B, MB] int32 ownership (scalar prefetch, seq split)
-    om_ref=None,  # [1, 1, T·g, 1] partials outputs (seq split)
+    om_ref=None,  # [1, KV, T·g, 1] partials outputs (seq split)
     ol_ref=None,
 ):
     b = pl.program_id(0)
-    mb = pl.program_id(2)
+    mb = pl.program_id(1)
 
     @pl.when(mb == 0)
     def _init():
@@ -78,38 +81,46 @@ def _paged_kernel(
 
     @pl.when(live)
     def _step():
-        q = q_ref[0, 0]  # [T·g, hd]
-        k = k_ref[0, :, 0]  # [BS, hd]
-        v = v_ref[0, :, 0]
-        if ks_ref is not None:  # dequantize in-kernel: int8 · scale/127
-            k = k.astype(jnp.float32) * (
-                ks_ref[0, :, 0].astype(jnp.float32) / 127.0
-            )[:, None]
-            v = v.astype(jnp.float32) * (
-                vs_ref[0, :, 0].astype(jnp.float32) / 127.0
-            )[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [T·g, BS]
-        pos = mb * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        tq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
+        shape = (t * g, bs)
+        pos = mb * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        tq = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // g
         mask = pos < base + tq + 1
-        s = jnp.where(mask, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        # the block holds every kv group (its trailing (KV, hd) dims are
+        # the pool's own, which is what the TPU tiling rule accepts);
+        # the groups are a short static loop over the one DMA'd block
+        for h in range(kv):
+            q = q_ref[0, h]  # [T·g, hd]
+            k = k_ref[0, :, h, :]  # [BS, hd]
+            v = v_ref[0, :, h, :]
+            if ks_ref is not None:
+                # dequantize in-kernel to the activation dtype, as the
+                # reference does: int8 · scale/127
+                k = (k.astype(jnp.float32) * (
+                    ks_ref[0, :, h : h + 1].astype(jnp.float32) / 127.0
+                )).astype(q.dtype)
+                v = (v.astype(jnp.float32) * (
+                    vs_ref[0, :, h : h + 1].astype(jnp.float32) / 127.0
+                )).astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [T·g, BS]
+            s = jnp.where(mask, s, NEG)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = m_new
 
-    @pl.when(mb == pl.num_programs(2) - 1)
+    @pl.when(mb == pl.num_programs(1) - 1)
     def _flush():
         if om_ref is None:
-            o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
                 o_ref.dtype
             )
         else:
@@ -117,9 +128,9 @@ def _paged_kernel(
             # cross-rank distributed_softmax combine normalizes. An
             # all-skipped shard flushes (NEG, 0, 0), which the combine's
             # empty-shard guard scales to exactly zero
-            o_ref[0, 0] = acc_ref[...]
-            om_ref[0, 0] = m_ref[...]
-            ol_ref[0, 0] = l_ref[...]
+            o_ref[0] = acc_ref[...]
+            om_ref[0] = m_ref[...]
+            ol_ref[0] = l_ref[...]
 
 
 def paged_decode_attention(
@@ -158,18 +169,20 @@ def paged_decode_attention(
     # ride together in the sublane dim: [B, KV, T·g, hd]
     qr = q.reshape(B, T, KV, g, hd).transpose(0, 2, 1, 3, 4).reshape(B, KV, T * g, hd)
 
-    grid = (B, KV, MB)
-    # index maps take *pref so one lambda serves both prefetch layouts
+    grid = (B, MB)
+    # every block's trailing two dims equal the array's own, which is
+    # what Mosaic's tiling rule asks of a block narrower than (8, 128).
+    # Index maps take *pref so one lambda serves both prefetch layouts
     # (tbl, lens) and (tbl, lens, owned)
     kv_spec = pl.BlockSpec(
-        (1, BS, 1, hd), lambda b, kv, mb, *pref: (pref[0][b, mb], 0, kv, 0)
+        (1, BS, KV, hd), lambda b, mb, *pref: (pref[0][b, mb], 0, 0, 0)
     )
-    q_spec = pl.BlockSpec((1, 1, T * g, hd), lambda b, kv, mb, *pref: (b, kv, 0, 0))
+    q_spec = pl.BlockSpec((1, KV, T * g, hd), lambda b, mb, *pref: (b, 0, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qr, k_pool, v_pool]
     if quant:
         sc_spec = pl.BlockSpec(
-            (1, BS, 1), lambda b, kv, mb, *pref: (pref[0][b, mb], 0, kv)
+            (1, BS, KV), lambda b, mb, *pref: (pref[0][b, mb], 0, 0)
         )
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
@@ -179,7 +192,7 @@ def paged_decode_attention(
         prefetch.append(owned.astype(jnp.int32))
 
     if partials:
-        ml_spec = pl.BlockSpec((1, 1, T * g, 1), lambda b, kv, mb, *pref: (b, kv, 0, 0))
+        ml_spec = pl.BlockSpec((1, KV, T * g, 1), lambda b, mb, *pref: (b, 0, 0, 0))
         out_specs = (q_spec, ml_spec, ml_spec)
         out_shape = (
             jax.ShapeDtypeStruct((B, KV, T * g, hd), jnp.float32),  # acc
@@ -207,7 +220,7 @@ def paged_decode_attention(
         mf, lf, accf = refs[i + n_out : i + n_out + 3]
         return _paged_kernel(
             tbl, lens, qf, kf, vf, of, mf, lf, accf,
-            scale=scale, bs=BS, t=T, g=g, ks_ref=ksf, vs_ref=vsf,
+            scale=scale, bs=BS, t=T, g=g, kv=KV, ks_ref=ksf, vs_ref=vsf,
             own_ref=own, om_ref=omf, ol_ref=olf,
         )
 
@@ -219,14 +232,14 @@ def paged_decode_attention(
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((T * g, 1), jnp.float32),
-                pltpu.VMEM((T * g, 1), jnp.float32),
-                pltpu.VMEM((T * g, hd), jnp.float32),
+                pltpu.VMEM((KV, T * g, 1), jnp.float32),
+                pltpu.VMEM((KV, T * g, 1), jnp.float32),
+                pltpu.VMEM((KV, T * g, hd), jnp.float32),
             ],
         ),
         out_shape=out_shape,
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(*prefetch, *operands)

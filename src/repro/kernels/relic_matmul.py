@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref):
     # "compute thread": contract the block the DMA stream fetched last step
@@ -68,7 +66,7 @@ def relic_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -113,7 +111,7 @@ def relic_gemv(
         out_specs=pl.BlockSpec((B, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, bn), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

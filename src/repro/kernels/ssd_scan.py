@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, dt_ref, y_ref, state_ref, *, Q):
     ic = pl.program_id(2)
@@ -98,7 +96,7 @@ def ssd_scan(
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, nc, Q, hd), xh.dtype),
         scratch_shapes=[pltpu.VMEM((N, hd), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

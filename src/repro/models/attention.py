@@ -263,13 +263,13 @@ def _kernel_cached_attention(q, k_cache, v_cache, cache_len, k_scale, v_scale, b
     bs = _dense_block_size(Smax)
     if bs < min(8, Smax):
         # a (near-)prime max_seq has no usable tiling: the kernel grid
-        # would degrade to up to Smax single-token blocks, all DMA and
-        # rescale overhead. Keep the semantics and take the reference
-        # numerics for this shape instead — the registry contract is
-        # "same tokens", and the advisor gate measures whatever runs.
-        return _cached_attention(
-            q, k_cache, v_cache, cache_len,
-            k_scale=k_scale, v_scale=v_scale, backend="reference",
+        # would degrade to up to Smax single-token blocks. A kernel
+        # backend never swaps in other numerics for such a shape
+        raise ValueError(
+            f"attention backend {backend!r}: max_seq={Smax} has no block "
+            f"divisor >= 8 (largest is {bs}) for the paged kernel's "
+            f"identity-table view of the [B={B}, {Smax}, KV, hd] cache; "
+            "choose a max_seq with a divisor >= 8, or backend='reference'"
         )
     tables = jnp.arange(B * (Smax // bs), dtype=jnp.int32).reshape(B, Smax // bs)
     return kernel_ops.paged_attention(

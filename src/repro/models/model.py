@@ -420,7 +420,7 @@ class Model:
                 f"divide mesh axis {axis!r} (size {tp}); ShardingRules "
                 "dropped the head mapping — serve replicated instead"
             )
-        from repro.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         h_loc, kv_loc, hd = cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.head_dim

@@ -22,7 +22,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import constrain, normal

@@ -17,10 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from repro import compat
-from repro.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -35,7 +32,7 @@ def ring_allgather_matmul(x_loc, w_loc, axis_name: str):
     Each step multiplies the chunk currently held while the next chunk is
     in flight (the DMA/MXU pair at ICI scale).
     """
-    p = compat.axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     t_l = x_loc.shape[0]
     acc = jnp.zeros((p * t_l, w_loc.shape[1]), x_loc.dtype)
@@ -61,7 +58,7 @@ def matmul_reducescatter(h_loc, w_loc, axis_name: str):
     y [T/P, D]: each step computes the partial for one peer's sequence
     chunk and passes the accumulating partial around the ring.
     """
-    p = compat.axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     t = h_loc.shape[0]
     t_l = t // p

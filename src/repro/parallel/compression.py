@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 
 def _quantize(g, scale):
     q = jnp.clip(jnp.round(g / scale), -127, 127)
@@ -32,7 +30,7 @@ def compressed_psum(g, axis_name: str, err):
     as g; zeros initially). Call inside shard_map/pjit with `axis_name`
     bound.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     g_fb = g + err
     scale = jnp.maximum(jnp.max(jnp.abs(g_fb)) / 127.0, 1e-12)
     # share one scale so the reduced payload dequantizes exactly

@@ -19,9 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from repro.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 

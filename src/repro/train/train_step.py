@@ -11,7 +11,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.compression import compressed_psum

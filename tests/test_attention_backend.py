@@ -11,10 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.kernels import ops, ref
@@ -401,19 +398,21 @@ def test_sharded_callers_stay_on_reference_path():
 
 
 def test_kernel_backend_degenerate_max_seq_falls_back_to_reference(served):
-    """A (near-)prime max_seq has no usable identity-table tiling: the
-    kernel backend keeps the semantics by taking the reference numerics
-    for that shape instead of a single-token-block grid."""
+    """A (near-)prime max_seq has no usable identity-table tiling. The
+    kernel backend does not swap in the reference numerics for that
+    shape: it refuses, naming the shape, and only an explicit reference
+    backend serves it."""
     from repro.models.attention import _dense_block_size
 
     assert _dense_block_size(64) == 64
     assert _dense_block_size(512) == 256
-    assert _dense_block_size(257) == 1  # prime → degenerate → fallback
+    assert _dense_block_size(257) == 1  # prime → degenerate → refused
     _, m, params, prompts = served
-    eng = ServingEngine(m, params, max_seq=37, attention_backend="interpret")
-    out = eng.generate(prompts[:2, :5], n_steps=3)
-    ref = ServingEngine(m, params, max_seq=37).generate(prompts[:2, :5], n_steps=3)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    eng = ServingEngine(m, params, max_seq=257, attention_backend="interpret")
+    with pytest.raises(ValueError, match="max_seq=257 has no block divisor"):
+        eng.generate(prompts[:2, :5], n_steps=3)
+    ref = ServingEngine(m, params, max_seq=257, attention_backend="reference")
+    assert np.asarray(ref.generate(prompts[:2, :5], n_steps=3)).shape == (2, 3)
 
 
 def test_backend_override_rejected_under_decode_plan(served):

@@ -175,8 +175,12 @@ def test_monolithic_bucketed_prefill_no_retrace(served):
 
 
 def test_bucketed_prefill_matches_exact(served):
-    """Padded+masked prefill is bitwise the exact-shape prefill: same
-    next-token logits, same committed KV rows and length."""
+    """Padded+masked prefill is the exact-shape prefill: the same greedy
+    next token, the same committed length, and next-token logits and
+    committed KV rows equal within float32 rounding. The padded call
+    reduces over a longer masked axis, so XLA may order the sums
+    differently; 1e-5 absolute on O(1) float32 logits is a few ulps of
+    that reordering and far below any gap that flips the argmax."""
     cfg, m, params = served
     s0 = 11
     prompt = np.random.default_rng(3).integers(0, cfg.vocab_size, size=(1, s0))
@@ -187,10 +191,14 @@ def test_bucketed_prefill_matches_exact(served):
     pad_logits, pad_cache = m.prefill(
         params, jnp.asarray(padded), 64, prompt_len=jnp.asarray([s0])
     )
-    np.testing.assert_array_equal(np.asarray(exact_logits), np.asarray(pad_logits))
+    exact_logits, pad_logits = np.asarray(exact_logits), np.asarray(pad_logits)
+    assert exact_logits.dtype == np.float32
+    np.testing.assert_array_equal(exact_logits.argmax(-1), pad_logits.argmax(-1))
+    np.testing.assert_allclose(pad_logits, exact_logits, rtol=0, atol=1e-5)
     assert int(pad_cache["len"][0]) == int(exact_cache["len"][0]) == s0
-    np.testing.assert_array_equal(  # committed KV rows identical too
-        np.asarray(exact_cache["k"][:, :, :s0]), np.asarray(pad_cache["k"][:, :, :s0])
+    np.testing.assert_allclose(  # committed KV rows equal too
+        np.asarray(pad_cache["k"][:, :, :s0]), np.asarray(exact_cache["k"][:, :, :s0]),
+        rtol=0, atol=1e-5,
     )
 
 

@@ -13,10 +13,7 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.core.tools import (
@@ -41,8 +38,8 @@ _STATE: dict = {}
 
 
 def _model_state():
-    """Lazy module singleton (not a fixture: the hypothesis stub calls
-    property tests with drawn args only, so they can't take fixtures).
+    """Lazy module singleton, shared by the module fixture and the
+    ``@given`` property tests (built once, not once per example).
     The engine is primed over the K × backend grid once — every test
     that switches mid-serve rides the same warmed trace families."""
     if not _STATE:

@@ -91,10 +91,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.hlo_cost import analyze
-try:
-    mesh = jax.make_mesh((2,4), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
-except AttributeError:  # jax 0.4.x: no AxisType
-    mesh = jax.make_mesh((2,4), ("data","model"))
+mesh = jax.make_mesh((2,4), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
 def f(x, ws):
     def body(c, w):
         y = jnp.tanh(c @ w)

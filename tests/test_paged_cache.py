@@ -9,10 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.models import Model
@@ -289,8 +286,8 @@ _MUT = {}
 
 
 def _mutation_fixture():
-    """Module memo (the hypothesis stub's ``given`` wrapper takes no
-    pytest fixtures): one reduced model plus one batch=1 dense prefill
+    """Module memo, built once for every ``@given`` example: one
+    reduced model plus one batch=1 dense prefill
     reused as the ``write_prefill`` payload."""
     if not _MUT:
         cfg = get_config("smollm-135m").reduced()

@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import pad_to_multiple
 from repro.core.overlap_model import CPU_HW, Microtask, OverlapModel

@@ -7,10 +7,7 @@ import pytest
 
 from repro.core.relic import relic_pfor
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 def test_granularity_larger_than_n_items():

@@ -132,13 +132,20 @@ def test_staggered_lengths_and_slot_reuse(served):
 
 
 def test_eos_finishes_early_and_frees_slot(served):
-    _, m, params, prompts = served
-    base = ServingEngine(m, params, max_seq=64).generate(prompts[:1], n_steps=4)
-    eos = int(base[0, 2])
+    """EOS fires on its first occurrence: pick a prompt whose greedy
+    stream first emits some token at index 2 (a tiny random model often
+    repeats one token, so the choice is made from the streams, not
+    assumed), make that token EOS, and the request stops after it."""
+    cfg, m, params, _ = served
+    cands = jax.random.randint(jax.random.key(2), (16, 8), 0, cfg.vocab_size)
+    base = np.asarray(ServingEngine(m, params, max_seq=64).generate(cands, n_steps=4))
+    rows = [i for i in range(len(base)) if base[i, 2] not in base[i, :2]]
+    assert rows, f"no candidate prompt emits a new token at index 2: {base}"
+    i = rows[0]
     eng = ServingEngine(m, params, max_seq=64)
-    req = Request(prompt=prompts[0], max_new_tokens=16, eos_id=eos)
+    req = Request(prompt=cands[i], max_new_tokens=16, eos_id=int(base[i, 2]))
     out = eng.serve([req], max_batch=2)
-    np.testing.assert_array_equal(out[req.rid], np.asarray(base[0, :3]))
+    np.testing.assert_array_equal(out[req.rid], base[i, :3])
     assert req.finished
 
 

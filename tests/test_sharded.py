@@ -24,12 +24,9 @@ HEADER = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
-except AttributeError:  # jax 0.4.x: no AxisType
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
 """
 
 

@@ -37,10 +37,7 @@ from repro.models.model import Model
 from repro.serve.engine import ServingEngine
 from repro.serve.request import Request
 from repro.serve.speculative import SpecConfig
-try:
-    mesh = jax.make_mesh(({tp},), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
-except AttributeError:  # jax 0.4.x: no AxisType
-    mesh = jax.make_mesh(({tp},), ("model",))
+mesh = jax.make_mesh(({tp},), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 
 # 4 kv heads so both 2- and 4-way meshes divide; g=2 exercises GQA grouping
 CFG = dataclasses.replace(
@@ -123,9 +120,6 @@ def _mesh_header(shape, names) -> str:
     	'jax.make_mesh((2,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))',
     	f'jax.make_mesh({shape!r}, {names!r}, '
     	f'axis_types=(jax.sharding.AxisType.Auto,) * {len(shape)})',
-    ).replace(
-    	'jax.make_mesh((2,), ("model",))',
-    	f'jax.make_mesh({shape!r}, {names!r})',
     )
 
 

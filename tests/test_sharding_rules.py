@@ -8,10 +8,7 @@ Runs against a duck-typed mesh (only ``mesh.shape`` is consulted by
 import dataclasses
 import math
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.parallel.sharding import ShardingRules

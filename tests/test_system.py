@@ -78,3 +78,42 @@ def test_train_then_serve_roundtrip():
     out = eng.generate(jnp.ones((2, 8), jnp.int32), n_steps=4)
     assert out.shape == (2, 4)
     assert eng.stats.percentile(50) > 0
+
+
+def test_sharded_sweep_never_reexecs_on_an_accelerator(monkeypatch):
+    """On an accelerator host with too few devices the sharded sweep
+    refuses, naming the count it needs: the CPU re-exec would start a
+    child that needs the chip the parent already holds."""
+    import pytest
+
+    from benchmarks import serving_load
+
+    def no_child(*a, **k):
+        raise AssertionError("re-exec attempted on an accelerator host")
+
+    monkeypatch.setattr(serving_load.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(serving_load, "_run_sharded_subprocess", no_child)
+    need = serving_load.sharded_devices_needed()
+    with pytest.raises(RuntimeError, match=f"needs {need} devices"):
+        serving_load.run_sharded()
+
+
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(monkeypatch):
+    from pathlib import Path
+
+    from repro import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None  # left to JAX
+        monkeypatch.delenv(compile_cache.ENV)
+        path = compile_cache.enable()
+        assert path == jax.config.jax_compilation_cache_dir
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert compile_cache.DEFAULT_DIR.parent == Path(__file__).resolve().parents[1]
+        assert compile_cache.enable() == path  # fixed: no pid, time or temp
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
