@@ -1057,7 +1057,7 @@ def run_observability(
         telemetry_mod.GLOBAL = was
 
     names = {e[1] for e in tel.tracer.events}
-    assert "step" in names, "no scheduler step span recorded"
+    assert "serve.step" in names, "no scheduler step span recorded"
     assert "speculation-decision" in names, "advisor decision not in trace"
     counts = validate_chrome_trace(tel.tracer.to_chrome_trace())
     if trace_path:

@@ -1,7 +1,7 @@
 """stablelm-3b [dense].
 
 32L d_model=2560 32H (GQA kv=32 → MHA) d_ff=6912 vocab=50304
-[hf:stabilityai/stablelm-2-1_6b; unverified]. Vocab padded 50304→50432.
+[hf:stabilityai/stablelm-3b-4e1t]. Vocab padded 50304→50432.
 """
 from repro.configs.base import ModelConfig, register
 

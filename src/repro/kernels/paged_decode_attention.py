@@ -242,6 +242,7 @@ def paged_decode_attention(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*prefetch, *operands)
 
     def heads_out(x, d):

@@ -6,7 +6,6 @@ the HLO stays compact at 48+ layers; remat policy wraps the scan body.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import jax
@@ -358,7 +357,15 @@ class Model:
         registry is consulted before tracing — a later registry change
         can never silently retarget an existing trace (DESIGN.md §4)."""
         backend = kernel_ops.resolve_attention_backend(backend)
-        return jax.jit(functools.partial(getattr(self, name), backend=backend))
+        step = getattr(self, name)
+
+        def run(*args, **kw):
+            return step(*args, backend=backend, **kw)
+
+        # the program's name in a profile: ``jit_serve_<name>``, not the
+        # ``jit__unknown`` a functools.partial would give
+        run.__name__ = f"serve_{name}"
+        return jax.jit(run)
 
     def paged_pool_specs(self, axis="model", seq_axis=None):
         """PartitionSpecs for the block-pool leaves under a serving mesh

@@ -693,38 +693,44 @@ class Scheduler:
                 )
             else:
                 prompts = jnp.stack([jnp.asarray(eff) for _, eff in group])
-            with self.tel.annotate("serve.prefill"):
+            with self.tel.span("serve.prefill"):
                 logits, cache = self._prefill(self.params, prompts, **kw)
             for i, (req, eff) in enumerate(group):
-                slot = self.kv.alloc(req.rid)
-                req.slot = slot
-                self.kv.write(slot, self.model.read_cache_slot(cache, i))
-                if req.tokens:
-                    self.stats.recomputed_tokens += len(eff)
-                    self._resume_decode(req, slot, now)
-                else:
-                    self._start_decode(req, slot, logits[i], now)
-                if self._drafter is not None and self._live_k > 0 and not req.finished:
-                    self._drafter.on_admit(slot, req)
+                with self.tel.span(
+                    "serve.admit_request", rid=req.rid, prompt_tokens=len(eff), hit_tokens=0
+                ):
+                    slot = self.kv.alloc(req.rid)
+                    req.slot = slot
+                    self.kv.write(slot, self.model.read_cache_slot(cache, i))
+                    if req.tokens:
+                        self.stats.recomputed_tokens += len(eff)
+                        self._resume_decode(req, slot, now)
+                    else:
+                        self._start_decode(req, slot, logits[i], now)
+                    if self._drafter is not None and self._live_k > 0 and not req.finished:
+                        self._drafter.on_admit(slot, req)
 
     def _start_chunk_slot(self, req: Request, now: float) -> None:
         """Slotted chunked admission: claim the slot now, prefill later
         in ``chunk_size`` slices. The slot's pool row holds junk until
         the install (its decode outputs are ignored — the row is not in
         ``_active`` — and ``kv.write`` overwrites everything)."""
-        self._note_admitted(req, now)
-        slot = self.kv.alloc(req.rid)
-        req.slot = slot
         eff = self._effective_prompt(req)
-        if req.tokens:
-            self.stats.recomputed_tokens += len(eff)
-        self._chunking[slot] = _ChunkState(
-            req=req,
-            cache=self.model.init_cache(1, self.max_seq),
-            prompt=eff,
-            pos=0,
-            skip_blocks=0,
-        )
+        with self.tel.span(
+            "serve.admit_request", rid=req.rid, prompt_tokens=len(eff), hit_tokens=0
+        ):
+            self._note_admitted(req, now)
+            slot = self.kv.alloc(req.rid)
+            req.slot = slot
+            if req.tokens:
+                self.stats.recomputed_tokens += len(eff)
+            self._chunking[slot] = _ChunkState(
+                req=req,
+                cache=self.model.init_cache(1, self.max_seq),
+                prompt=eff,
+                pos=0,
+                skip_blocks=0,
+            )
 
     def _try_admit_paged(self, req: Request, now: float) -> bool:
         """Paged admission, one request at a time: prefix-match the
@@ -739,12 +745,6 @@ class Scheduler:
         resume = bool(req.tokens)
         eff = self._effective_prompt(req)
         n_cache = len(eff)
-        tokens = tuple(int(t) for t in eff)
-        if req.patch_embeds is not None:
-            # patch embeddings occupy cache rows ahead of the tokens and
-            # are not token-addressable — no prefix matching for them
-            n_cache += int(jnp.asarray(req.patch_embeds).shape[0])
-            tokens = ()
         # the block budget carries the speculative margin: the rejected
         # tail of a verify transiently occupies blocks past the final
         # committed length, and lazy tail claims must never fail. A
@@ -754,29 +754,50 @@ class Scheduler:
         budget = req.max_new_tokens + self._spec_margin
         if resume:
             budget -= len(req.tokens) - 1
-        got = self.kv.try_admit(
-            req.rid,
-            tokens,
-            budget,
-            n_tokens=n_cache,
-            register=self.chunk_size is None,
-        )
+        with self.tel.span("serve.prefix_match"):
+            tokens = tuple(int(t) for t in eff)
+            if req.patch_embeds is not None:
+                # patch embeddings occupy cache rows ahead of the tokens and
+                # are not token-addressable — no prefix matching for them
+                n_cache += int(jnp.asarray(req.patch_embeds).shape[0])
+                tokens = ()
+            got = self.kv.try_admit(
+                req.rid,
+                tokens,
+                budget,
+                n_tokens=n_cache,
+                register=self.chunk_size is None,
+            )
         if got is None:
             return False
         row, hit_ids = got
+        hit = len(hit_ids) * self.kv.block_size
+        with self.tel.span(
+            "serve.admit_request", rid=req.rid, prompt_tokens=n_cache, hit_tokens=hit
+        ):
+            self._admit_paged_row(req, row, eff, hit_ids, now)
+        return True
+
+    def _admit_paged_row(self, req: Request, row: int, eff, hit_ids, now: float) -> None:
+        """The admitted request's work: with chunking, its prefill state;
+        else the prefill of the un-cached suffix (gathering the hit
+        prefix first), its install into the pool, and its first token."""
+        resume = bool(req.tokens)
         self._note_admitted(req, now)
         req.slot = row
         hit = len(hit_ids) * self.kv.block_size
         if resume:
-            self.stats.recomputed_tokens += n_cache - hit
+            self.stats.recomputed_tokens += int(self.kv.cache_len[row]) - hit
         else:
             # prefix_hit stays the FIRST admission's hit: it feeds the
             # prompt-token prefix_hit_rate, where resume recompute
             # accounting would double-count the same prompt tokens
             req.prefix_hit = hit
+        if hit:
+            with self.tel.span("serve.gather_prefix"):
+                pk, pv = self.kv.gather_prefix(hit_ids)
         if self.chunk_size is not None:
             if hit:
-                pk, pv = self.kv.gather_prefix(hit_ids)
                 cache = self.model.seed_cache_with_prefix(pk, pv, self.max_seq)
             else:
                 cache = self.model.init_cache(1, self.max_seq)
@@ -784,10 +805,9 @@ class Scheduler:
                 req=req, cache=cache, prompt=eff, pos=hit,
                 skip_blocks=len(hit_ids),
             )
-            return True
+            return
         prompt_dev = jnp.asarray(eff)
         if hit:
-            pk, pv = self.kv.gather_prefix(hit_ids)
             suffix = prompt_dev[hit:]
             Ssuf = int(suffix.shape[0])
             kw = {}
@@ -799,7 +819,7 @@ class Scheduler:
                 padded[0, :Ssuf] = np.asarray(suffix)
                 suffix = jnp.asarray(padded)[0]
                 kw["suffix_len"] = jnp.asarray([Ssuf], jnp.int32)
-            with self.tel.annotate("serve.prefill"):
+            with self.tel.span("serve.prefill"):
                 logits, cache = self._prefill_prefix(
                     self.params, suffix[None, :], pk, pv, **kw
                 )
@@ -819,16 +839,16 @@ class Scheduler:
                 padded[0, :S] = np.asarray(prompt_dev)
                 prompt_dev = jnp.asarray(padded)[0]
                 kw["prompt_len"] = jnp.asarray([S], jnp.int32)
-            with self.tel.annotate("serve.prefill"):
+            with self.tel.span("serve.prefill"):
                 logits, cache = self._prefill(self.params, prompt_dev[None, :], **kw)
-        self.kv.write_prefill(row, cache, skip_blocks=len(hit_ids))
+        with self.tel.span("serve.write_prefill"):
+            self.kv.write_prefill(row, cache, skip_blocks=len(hit_ids))
         if resume:
             self._resume_decode(req, row, now)
         else:
             self._start_decode(req, row, logits[0], now)
         if self._drafter is not None and self._live_k > 0 and not req.finished:
             self._drafter.on_admit(row, req)
-        return True
 
     # ------------------------------------------------------------------
     # priority preemption
@@ -900,17 +920,18 @@ class Scheduler:
         """One batched decode over the full row pool; returns logits and
         installs the new cache."""
         if self.kv_layout == "paged":
-            for row in self._active:
-                self.kv.ensure_tail(row)
-            pool, tables, lens = self.kv.kernel_inputs()
-            with self.tel.annotate("serve.decode"):
+            with self.tel.span("serve.decode_inputs"):
+                for row in self._active:
+                    self.kv.ensure_tail(row)
+                pool, tables, lens = self.kv.kernel_inputs()
+            with self.tel.span("serve.decode", **self._decode_args()):
                 logits, new_pool = self._decode_paged(
                     self.params, pool, tables, lens, self._tok[:, None]
                 )
-            logits.block_until_ready()
+                logits.block_until_ready()
             self.kv.pool = new_pool
             return logits
-        with self.tel.annotate("serve.decode"):
+        with self.tel.span("serve.decode", **self._decode_args()):
             if self._decode_plan is not None:
                 logits, new_cache = self._plan_decode(
                     self.kv.cache, self._tok, jnp.asarray(mask)
@@ -919,9 +940,22 @@ class Scheduler:
                 logits, new_cache = self._decode(
                     self.params, self.kv.cache, self._tok[:, None]
                 )
-        logits.block_until_ready()
+            logits.block_until_ready()
         self.kv.cache = new_cache
         return logits
+
+    def _decode_args(self) -> dict:
+        """The decode span's args, read from host state only when the
+        tracer is on: the rows decoded and, on the paged layout (whose
+        lengths live on the host), the context they attend over — each
+        live row's committed length plus the token being decoded."""
+        if not self._ton:
+            return {}
+        rows = list(self._active)
+        args = {"live_rows": len(rows)}
+        if self.kv_layout == "paged":
+            args["context_tokens"] = int(self.kv.cache_len[rows].sum()) + len(rows)
+        return args
 
     def _spec_step(self) -> None:
         """One fused draft→verify speculation round over the full pool.
@@ -938,7 +972,7 @@ class Scheduler:
         """
         K = self._live_k
         t_start = time.perf_counter()
-        with self.tel.annotate("serve.draft"):
+        with self.tel.span("serve.draft", k=K):
             drafts = self._drafter.propose(self._active, np.asarray(self._tok))
         t_draft = time.perf_counter()
         self.stats.draft_ms.append((t_draft - t_start) * 1e3)
@@ -946,32 +980,33 @@ class Scheduler:
             [self._tok[:, None], jnp.asarray(drafts, jnp.int32)], axis=1
         )
         if self.kv_layout == "paged":
-            for row in self._active:
-                self.kv.ensure_tail_n(row, K + 1)
-            pool, tables, lens = self.kv.kernel_inputs()
-            with self.tel.annotate("serve.verify"):
+            with self.tel.span("serve.decode_inputs"):
+                for row in self._active:
+                    self.kv.ensure_tail_n(row, K + 1)
+                pool, tables, lens = self.kv.kernel_inputs()
+            with self.tel.span("serve.verify", k=K):
                 logits, new_pool = self._verify_paged(
                     self.params, pool, tables, lens, tokens_in
                 )
-            logits.block_until_ready()
+                logits.block_until_ready()
             self.kv.pool = new_pool
         else:
-            with self.tel.annotate("serve.verify"):
+            with self.tel.span("serve.verify", k=K):
                 logits, new_cache = self._verify(self.params, self.kv.cache, tokens_in)
-            logits.block_until_ready()
+                logits.block_until_ready()
             self.kv.cache = new_cache
         greedy = np.asarray(jnp.argmax(logits, axis=-1))  # [max_batch, K+1]
-        now = time.perf_counter()
-        self.stats.verify_ms.append((now - t_draft) * 1e3)
-        if self._ton:
-            tr = self.tel.tracer
-            a, b = tr.to_us(t_start), tr.to_us(t_draft)
-            tr.complete("draft", "sched", a, b - a, args={"k": K})
-            tr.complete("verify", "sched", b, tr.to_us(now) - b, args={"k": K})
+        self.stats.verify_ms.append((time.perf_counter() - t_draft) * 1e3)
         self.stats.spec_k = K
         self.stats.spec_steps += 1
+        with self.tel.span("serve.sample"):
+            self._accept(drafts, greedy, K)
 
-        # acceptance: commit matched prefix + corrected token, per row
+    def _accept(self, drafts, greedy, K: int) -> None:
+        """Greedy-equivalence acceptance of one verify round: commit
+        each row's matched draft prefix plus the corrected token, retire
+        rows that reach their budget or EOS, and rewind the rejected
+        tails of the pools and the drafter."""
         rej = np.full((self.kv.max_batch,), K + 1, np.int32)
         for row, req in list(self._active.items()):
             d, g = drafts[row], greedy[row]
@@ -1068,7 +1103,7 @@ class Scheduler:
             n = min(n, W)
             toks = np.zeros((1, W), np.int32)
             toks[0, :n] = st.prompt[st.pos : st.pos + n]
-            with self.tel.annotate("serve.prefill_chunk"):
+            with self.tel.span("serve.prefill_chunk"):
                 logits, st.cache = self._prefill_chunk(
                     self.params, st.cache, jnp.asarray(toks),
                     jnp.asarray([n], jnp.int32),
@@ -1097,7 +1132,8 @@ class Scheduler:
         blocks hold real KV; slotted rows install the whole dense cache."""
         req = st.req
         if self.kv_layout == "paged":
-            self.kv.write_prefill(row, st.cache, skip_blocks=st.skip_blocks)
+            with self.tel.span("serve.write_prefill"):
+                self.kv.write_prefill(row, st.cache, skip_blocks=st.skip_blocks)
             self.kv.register_prompt(row, tuple(int(t) for t in st.prompt))
         else:
             self.kv.write(row, st.cache)
@@ -1129,29 +1165,14 @@ class Scheduler:
             jax.block_until_ready(logits)
             W *= 2
 
-    def _finish_step(self, t0: float, phases) -> None:
-        """Telemetry step boundary (only reached with the tracer on):
-        emit the step span + its non-empty phase children, refresh the
-        pool/queue gauges, sample the jit caches for retraces, and tick
-        the registry so every counter lands in its window ring. All
-        host-side bookkeeping — no device syncs beyond the ones the
-        step already performed."""
-        end = time.perf_counter()
-        tr = self.tel.tracer
-        ts0 = tr.to_us(t0)
-        self._step_seq += 1
-        tr.complete(
-            "step", "sched", ts0, tr.to_us(end) - ts0,
-            args={
-                "seq": self._step_seq,
-                "active": len(self._active),
-                "queued": len(self._queue),
-            },
-        )
-        for name, a, b, did in phases:
-            if did:
-                ta = tr.to_us(a)
-                tr.complete(name, "sched", ta, tr.to_us(b) - ta)
+    def _finish_step(self, span) -> None:
+        """Telemetry step boundary (only reached with the tracer on, for
+        a step that did work): note the live and queued counts on the
+        step's ring event, refresh the pool/queue gauges, sample the jit
+        caches for retraces, and tick the registry so every counter
+        lands in its window ring. All host-side bookkeeping — no device
+        syncs beyond the ones the step already performed."""
+        span.note(active=len(self._active), queued=len(self._queue))
         self._g_queue.set(len(self._queue))
         self._g_active.set(len(self._active))
         self._g_occ.set(self.kv.occupancy)
@@ -1160,7 +1181,9 @@ class Scheduler:
         size = sum(f._cache_size() for f in self._traced_fns)
         if size > self._cache_size_seen:
             self._c_retraces.inc(size - self._cache_size_seen)
-            tr.instant("retrace", "sched", args={"new": size - self._cache_size_seen})
+            self.tel.tracer.instant(
+                "retrace", "sched", args={"new": size - self._cache_size_seen}
+            )
         self._cache_size_seen = size
         self.stats.registry.tick()
 
@@ -1180,62 +1203,48 @@ class Scheduler:
     def _step_inner(self, now: Optional[float] = None) -> bool:
         if now is None:
             now = self._clock()
+        if not self._ton:
+            return self._step_body(now)
+        self._step_seq += 1
+        with self.tel.span("serve.step", seq=self._step_seq) as span:
+            did = self._step_body(now)
+            if did:
+                self._finish_step(span)
+        return did
+
+    def _step_body(self, now: float) -> bool:
         t0 = time.perf_counter()
-        ton = self._ton
-        admitted = self._admit_phase(now)
-        t_admit = time.perf_counter() if ton else 0.0
+        with self.tel.span("serve.admit"):
+            admitted = self._admit_phase(now)
         chunked = self._prefill_phase(now)
-        t_chunk = time.perf_counter() if ton else 0.0
         if not self._active:
             if admitted or chunked:
                 self.stats.step_ms.append((time.perf_counter() - t0) * 1e3)
-                if ton:
-                    self._finish_step(
-                        t0,
-                        (("admit", t0, t_admit, admitted),
-                         ("prefill_chunk", t_admit, t_chunk, chunked)),
-                    )
                 return True
             return False
         if self.spec is not None and self._live_k > 0:
             self._spec_step()
             self.stats.step_ms.append((time.perf_counter() - t0) * 1e3)
-            if ton:
-                # draft/verify phase spans were emitted inside _spec_step
-                self._finish_step(
-                    t0,
-                    (("admit", t0, t_admit, admitted),
-                     ("prefill_chunk", t_admit, t_chunk, chunked)),
-                )
             return True
 
         mask = self.kv.live_mask()
         logits = self._decode_pool(mask)
-        t_decode = time.perf_counter() if ton else 0.0
         self.stats.step_ms.append((time.perf_counter() - t0) * 1e3)
-        if self.kv_layout == "paged":
-            for row in self._active:
-                self.kv.advance(row)
-
-        keys, subs = jax.vmap(jax.random.split, out_axes=1)(self._keys)
-        nxt = jax.vmap(self._sample_row)(logits, subs)
-        live = jnp.asarray(mask)
-        self._tok = jnp.where(live, nxt, self._tok)
-        self._keys = jnp.where(live[:, None], keys, self._keys)
-        nxt_host = np.asarray(nxt)
-        for row, req in list(self._active.items()):
-            tok = int(nxt_host[row])
-            req.tokens.append(tok)
-            if len(req.tokens) >= req.max_new_tokens or tok == req.eos_id:
-                self._retire(req, self._clock())
-        if ton:
-            self._finish_step(
-                t0,
-                (("admit", t0, t_admit, admitted),
-                 ("prefill_chunk", t_admit, t_chunk, chunked),
-                 ("decode", t_chunk, t_decode, True),
-                 ("sample", t_decode, time.perf_counter(), True)),
-            )
+        with self.tel.span("serve.sample"):
+            if self.kv_layout == "paged":
+                for row in self._active:
+                    self.kv.advance(row)
+            keys, subs = jax.vmap(jax.random.split, out_axes=1)(self._keys)
+            nxt = jax.vmap(self._sample_row)(logits, subs)
+            live = jnp.asarray(mask)
+            self._tok = jnp.where(live, nxt, self._tok)
+            self._keys = jnp.where(live[:, None], keys, self._keys)
+            nxt_host = np.asarray(nxt)
+            for row, req in list(self._active.items()):
+                tok = int(nxt_host[row])
+                req.tokens.append(tok)
+                if len(req.tokens) >= req.max_new_tokens or tok == req.eos_id:
+                    self._retire(req, self._clock())
         return True
 
     def run(self, requests=None, *, reset_stats: bool = True) -> dict:

@@ -235,7 +235,7 @@ class ModelDraftSource:
         cur = jnp.asarray(np.asarray(tok, np.int32))
         cache = self.cache
         out = []
-        with get_telemetry().annotate("serve.draft_model"):
+        with get_telemetry().span("serve.draft_model"):
             for _ in range(self.k):
                 logits, cache = self._decode(self.params, cache, cur[:, None])
                 cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -14,9 +14,10 @@ measurement substrate (DESIGN.md §8):
     Chrome/Perfetto trace-event JSON (load in ``ui.perfetto.dev`` or
     ``chrome://tracing``):
 
-    * per-step **phase** events (admit / prefill-chunk / draft / verify
-      / decode / sample) as complete ``"X"`` spans on the scheduler
-      lane, nested under one span per scheduler step;
+    * the scheduler's ``serve.*`` spans (``Telemetry.span``: step,
+      admit, admit_request, prefill, write_prefill, decode, sample,
+      ...) as complete ``"X"`` events on the scheduler lane, nested
+      as they ran;
     * per-request **lifecycle** spans as async ``"b"``/``"n"``/``"e"``
       events keyed by request id (queued → admit → prefill-chunk* →
       first-token → preempt/resume → finish);
@@ -33,21 +34,23 @@ measurement substrate (DESIGN.md §8):
     the last *n* steps" — acceptance rate, queue depth, pool occupancy,
     step cost — exactly the signal vector the future online adviser
     (ROADMAP "online adaptive adviser") will consume.  ``snapshot()``
-    returns a JSON-ready dict and ``prometheus_text()`` a
-    Prometheus-style text exposition.
+    returns a JSON-ready dict.
 
 ``Telemetry``
-    Bundles a tracer (+ optional ``jax.profiler`` annotations) behind
-    one ``enabled`` flag — the hard off-switch.  Disabled (the
-    default), every instrumentation site in the serving hot path is a
-    single attribute check and the code path is today's: no events, no
-    ticks, no annotations.  A module-global default (``get_telemetry``)
+    Bundles a tracer behind one ``enabled`` flag — the hard
+    off-switch — and owns the one span API, ``span(name, **args)``,
+    with two sinks: a ``jax.profiler.TraceAnnotation`` (with
+    ``xla_annotations``), which lands in the profiler trace on the
+    device planes' clock, and an ``"X"`` event on the tracer's ring.
+    Disabled (the default), every instrumentation site in the serving
+    hot path is a single attribute check: no events, no clock reads,
+    no ticks, no annotations.  A module-global default (``get_telemetry``)
     serves call sites with no engine handle (kernel backend registry,
     adviser tools); engines and schedulers accept an explicit
     ``telemetry=`` for isolation in tests.
 
 This module is stdlib + numpy only (``jax.profiler`` imported lazily
-inside ``annotate``) so ``core/`` and ``kernels/`` can use it without
+by the first enabled ``span``) so ``core/`` and ``kernels/`` can use it without
 an import cycle through the serving package.
 """
 from __future__ import annotations
@@ -65,6 +68,7 @@ __all__ = [
     "Series",
     "MetricsRegistry",
     "Tracer",
+    "Span",
     "Telemetry",
     "get_telemetry",
     "configure",
@@ -304,28 +308,6 @@ class MetricsRegistry:
             },
         }
 
-    def prometheus_text(self) -> str:
-        """Prometheus text exposition (``.`` → ``_`` in names; series
-        exported as summary quantiles + count)."""
-        lines: list[str] = []
-        for name, c in sorted(self._counters.items()):
-            pname = name.replace(".", "_")
-            lines.append(f"# TYPE {pname} counter")
-            lines.append(f"{pname} {c.value:g}")
-        for name, g in sorted(self._gauges.items()):
-            if g.value is None:
-                continue
-            pname = name.replace(".", "_")
-            lines.append(f"# TYPE {pname} gauge")
-            lines.append(f"{pname} {g.value:g}")
-        for name, s in sorted(self._series.items()):
-            pname = name.replace(".", "_")
-            lines.append(f"# TYPE {pname} summary")
-            lines.append(f'{pname}{{quantile="0.5"}} {s.quantile(50.0):g}')
-            lines.append(f'{pname}{{quantile="0.99"}} {s.quantile(99.0):g}')
-            lines.append(f"{pname}_count {len(s)}")
-        return "\n".join(lines) + "\n"
-
     def reset(self) -> None:
         """Zero counters, clear gauges/series/rings IN PLACE — metric
         objects cached by hot-path call sites stay valid."""
@@ -443,17 +425,52 @@ class Tracer:
         return trace
 
 
+class Span:
+    """An enabled ``Telemetry.span``: a profiler annotation opened on
+    entry (with ``xla_annotations``), and one ``"X"`` event on the
+    tracer's ring on exit, under the same name and args."""
+
+    __slots__ = ("_tel", "name", "args", "_ann", "_t0")
+
+    def __init__(self, tel: Telemetry, name: str, args: dict):
+        self._tel = tel
+        self.name = name
+        self.args = args
+        self._ann = None
+        self._t0 = 0.0
+
+    def note(self, **args) -> None:
+        """Args known only at the end: a profiler annotation's args are
+        fixed when it opens, so these go on the ring event only."""
+        self.args.update(args)
+
+    def __enter__(self) -> Span:
+        if self._tel.xla_annotations:
+            self._ann = self._tel._annotation(self.name, self.args)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr = self._tel.tracer
+        tr.complete(self.name, "sched", tr.to_us(self._t0), (t1 - self._t0) * 1e6,
+                    args=self.args or None)
+
+
 class Telemetry:
     """Tracer (+ optional XLA annotations) behind one hard off-switch.
 
     ``enabled=False`` (the default, and the module global's state) is
-    the off-switch the tentpole requires: every instrumentation site
-    guards on ``tel.enabled`` (or on a cached metric handle that is
-    ``None`` when disabled), so the serving hot path is unchanged.
-    ``xla_annotations=True`` additionally wraps device-launching phases
-    in ``jax.profiler.TraceAnnotation`` so XLA device profiles carry
-    our phase names — off by default even when tracing, since it adds
-    a TraceMe per launch."""
+    the off-switch: every instrumentation site guards on ``tel.enabled``
+    (or on a cached metric handle that is ``None`` when disabled), and
+    ``span`` returns a shared no-op, so the serving hot path is
+    unchanged. ``xla_annotations=True`` additionally opens a
+    ``jax.profiler.TraceAnnotation`` per span, so a profiler trace
+    carries the program's span names beside the device's ops — off by
+    default even when tracing, since it adds a TraceMe per span."""
 
     def __init__(
         self,
@@ -466,17 +483,22 @@ class Telemetry:
         self.tracer = Tracer(capacity)
         self._annotation_cls = None
 
-    def annotate(self, name: str):
-        """Context manager for a device-launching phase: a
-        ``jax.profiler.TraceAnnotation`` when enabled AND
-        ``xla_annotations`` is set, else a shared no-op context."""
-        if not (self.enabled and self.xla_annotations):
+    def span(self, name: str, **args):
+        """Context manager around one piece of host work: a ``Span``
+        when enabled, else the shared no-op context (one attribute
+        check, no clock read, no annotation). ``args`` are small
+        numbers fixed at entry; ``Span.note`` adds end-of-span args to
+        the ring event."""
+        if not self.enabled:
             return _NULL_CM
+        return Span(self, name, args)
+
+    def _annotation(self, name: str, args: dict):
         if self._annotation_cls is None:
             from jax.profiler import TraceAnnotation  # lazy: keep module jax-free
 
             self._annotation_cls = TraceAnnotation
-        return self._annotation_cls(name)
+        return self._annotation_cls(name, **args)
 
     def count(self, name: str, n: float = 1.0, registry: MetricsRegistry | None = None) -> None:
         """Convenience for rare, engine-less call sites (backend

@@ -6,7 +6,7 @@ admission throttling, retrace-free live switching (randomized mid-serve
 K/backend decisions → zero new jit compiles after ``prime()``), token
 identity under any decision sequence (a pinned controller == the static
 configuration, bitwise), the ModelDraftSource 0→K catch-up, controller
-observability surfaces (Prometheus text, registry snapshot,
+observability surfaces (registry snapshot,
 ``serving_summary()["controller"]``), and ``window_summary`` cold-start
 finiteness."""
 import jax
@@ -407,9 +407,8 @@ def test_controller_metrics_in_prometheus_and_snapshot(served):
     assert "controller.k" in snap["gauges"]
     assert "controller.dwell_remaining" in snap["gauges"]
     assert "controller.backend_index" in snap["gauges"]
-    text = reg.prometheus_text()
-    assert "# TYPE controller_decisions counter" in text
-    assert "controller_k" in text
+    assert snap["counters"]["controller.decisions"] == reg.counter("controller.decisions").value
+    assert snap["gauges"]["controller.k"] == 2.0
     # a controller-less serve carries no controller key in the summary
     eng.serve(_workload(cfg.vocab_size), max_batch=2, seed=0,
               spec=SpecConfig(k=0))

@@ -2,10 +2,12 @@
 linear-interpolation quantile against numpy.percentile, registry
 windows/exposition, the tracer's ring bound and Chrome-trace schema,
 the hard off-switch (telemetry on == off token streams, no events when
-disabled), trace well-formedness over random open-loop traffic
-(exactly one terminal event per admitted request, step/phase spans
+disabled; a disabled ``span`` builds no annotation and reads no extra
+clock), trace well-formedness over random open-loop traffic
+(exactly one terminal event per admitted request, ``serve.*`` spans
 nest), the ServeStats→registry refactor's golden ``serving_summary``
-schema, XLA-annotation no-op smoke, and the adviser audit trail
+schema, the span API's XLA-annotation smoke, the decode program's
+stable name, and the adviser audit trail
 (advisor decisions + ToolPipeline stage spans land in the trace with
 their priced inputs)."""
 import json
@@ -145,11 +147,8 @@ def test_prometheus_and_snapshot_smoke():
     assert snap["counters"]["pool.alloc"] == 3.0
     assert snap["gauges"]["sched.queue_depth"] == 2.0
     assert snap["series"]["serve.step_ms"]["count"] == 2
-    text = reg.prometheus_text()
-    assert "# TYPE pool_alloc counter" in text
-    assert "pool_alloc 3" in text
-    assert 'serve_step_ms{quantile="0.5"}' in text
-    assert "serve_step_ms_count 2" in text
+    assert snap["series"]["serve.step_ms"]["p50"] == pytest.approx(1.5)
+    assert snap["ticks"] == 0
     json.dumps(snap)  # JSON-ready
 
 
@@ -249,11 +248,89 @@ def test_xla_annotations_noop_smoke(served):
                     telemetry=annotated)
     for a, b in zip(base.values(), out.values()):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # disabled or un-annotated telemetry shares one no-op context
+    # disabled telemetry shares one no-op context
     off = Telemetry(enabled=False)
-    assert off.annotate("x") is off.annotate("y")
-    with Telemetry(enabled=True, xla_annotations=True).annotate("phase"):
+    assert off.span("x") is off.span("y", rid=3)
+    with Telemetry(enabled=True, xla_annotations=True).span("phase", rid=3):
         pass  # TraceAnnotation enters/exits cleanly outside any profile
+    # the same run's spans land on the ring under the program's names
+    names = {e[1] for e in annotated.tracer.events if e[0] == "X"}
+    assert {"serve.step", "serve.admit", "serve.admit_request", "serve.prefill",
+            "serve.write_prefill", "serve.decode_inputs", "serve.decode",
+            "serve.sample"} <= names
+
+
+def test_span_off_builds_no_annotation_and_reads_no_extra_clock(served, monkeypatch):
+    """Telemetry off: ``span`` is the shared no-op, a served run builds
+    no ``TraceAnnotation``, reads no clock inside telemetry, records no
+    ring event, and each step reads the scheduler's clock only where
+    the step itself needs it (its start, its ``step_ms``, and a request's
+    first token or finish). The tokens are those of a traced run."""
+    import time
+    import types
+
+    import repro.serve.scheduler as sched_mod
+    import repro.serve.telemetry as telemetry_mod
+
+    cfg, _, _, eng = _model_state()
+    built = []
+
+    class CountingAnnotation(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    reads = {"sched": 0, "tel": 0}
+
+    def clock(who):
+        def perf_counter():
+            reads[who] += 1
+            return time.perf_counter()
+
+        return types.SimpleNamespace(perf_counter=perf_counter, sleep=time.sleep)
+
+    def serve(tel):
+        sched = eng.scheduler(2, seed=0, telemetry=tel)
+        reqs = _workload(cfg.vocab_size)
+        sched._t0 = time.perf_counter()
+        for r in reqs:
+            sched.submit(r)
+        extra = []  # per step: clock reads beyond the step's own needs
+        while sched._queue or sched._active:
+            n0 = reads["sched"]
+            firsts = sum(r.t_first is not None for r in reqs)
+            done = sum(r.finished for r in reqs)
+            did = sched.step()
+            need = (3 if did else 2) + (
+                sum(r.t_first is not None for r in reqs) - firsts
+            ) + (sum(r.finished for r in reqs) - done)
+            extra.append(reads["sched"] - n0 - need)
+        return [np.asarray(r.output()) for r in reqs], extra
+
+    off = Telemetry(enabled=False)
+    assert off.span("serve.step", seq=1) is off.span("serve.decode")
+    monkeypatch.setattr(sched_mod, "time", clock("sched"))
+    monkeypatch.setattr(telemetry_mod, "time", clock("tel"))
+    out_off, extra = serve(off)
+    assert built == [] and reads["tel"] == 0 and len(off.tracer) == 0
+    assert extra and set(extra) == {0}, extra
+
+    on = Telemetry(enabled=True, xla_annotations=True)
+    out_on, _ = serve(on)
+    assert "serve.decode" in built and reads["tel"] > 0 and len(on.tracer) > 0
+    for a, b in zip(out_off, out_on):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_decode_program_has_a_stable_name(served):
+    """The engine's paged decode program is named for what it runs, so a
+    profile's module line reads ``jit_serve_decode_step_paged``."""
+    _, _, params, eng = _model_state()
+    sched = eng.scheduler(2, seed=0)
+    pool, tables, lens = sched.kv.kernel_inputs()
+    text = sched._decode_paged.lower(params, pool, tables, lens, sched._tok[:, None]).as_text()
+    assert text.startswith("module @jit_serve_decode_step_paged ")
 
 
 # ---------------------------------------------------------------------------
