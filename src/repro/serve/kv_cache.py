@@ -25,6 +25,7 @@ for pages), so this module never inspects cache leaves itself.
 from __future__ import annotations
 
 import bisect
+import functools
 import math
 from typing import Optional
 
@@ -52,6 +53,84 @@ def local_table_view(tables, nb_loc: int, rank):
     owned = (tables // nb_loc) == rank
     local = jnp.where(owned, tables % nb_loc, nb_loc - 1)
     return local.astype(tables.dtype), owned
+
+
+# Pool blocks one install window spans: one 128-lane tile row of the TPU
+# layout XLA gives a pool leaf, whose block axis is the minor one.
+INSTALL_WINDOW = 128
+
+
+def _place_bits(payload, onehot):
+    """``out[:, c] = payload[:, j]`` for the ``j`` with ``onehot[j, c]``,
+    bit for bit, as a contraction over ``j`` on the matrix unit: each
+    byte of the payload's bits goes through as an int8 (byte − 128) with
+    int32 accumulation, so no value is rounded. Columns with no ``j``
+    hold junk. ``payload`` is ``[L, n, ...]``, ``onehot`` ``[n, W]``."""
+    width = payload.dtype.itemsize * 8
+    ut = {8: jnp.uint8, 16: jnp.uint16, 32: jnp.uint32}[width]
+    bits = jax.lax.bitcast_convert_type(payload, ut)
+    oh = onehot.astype(jnp.int8)
+    out = jnp.zeros((), ut)
+    for shift in range(0, width, 8):
+        byte = (((bits >> shift) & 0xFF).astype(jnp.int32) - 128).astype(jnp.int8)
+        got = jnp.einsum("lj...,jc->lc...", byte, oh, preferred_element_type=jnp.int32)
+        out = out | ((got + 128).astype(ut) << shift)
+    return jax.lax.bitcast_convert_type(out, payload.dtype)
+
+
+def _install_windows(leaf, payload, dst):
+    """``leaf[:, dst[j]] = payload[:, j]`` for every ``dst[j]`` inside the
+    leaf, one ``INSTALL_WINDOW``-block window at a time: read a window
+    that holds a ``dst``, place the payload's blocks into its columns,
+    write it back. The last window may overlap the one before it; a
+    block in both is written twice with the same bits."""
+    nb = leaf.shape[1]
+    w = min(INSTALL_WINDOW, nb)
+    n_win = -(-nb // w)
+    valid = (dst >= 0) & (dst < nb)
+    touched = jnp.zeros((n_win + 1,), bool).at[jnp.where(valid, dst // w, n_win)].set(True)
+    order = jnp.argsort(~touched[:n_win])  # touched windows first
+
+    def one_window(k, acc):
+        start = jnp.minimum(order[k] * w, nb - w)
+        col = dst - start
+        onehot = (valid & (col >= 0) & (col < w))[:, None] & (
+            col[:, None] == jnp.arange(w)[None, :]
+        )
+        keep = ~onehot.any(0).reshape((1, w) + (1,) * (acc.ndim - 2))
+        win = jax.lax.dynamic_slice_in_dim(acc, start, w, axis=1)
+        new = jnp.where(keep, win, _place_bits(payload, onehot))
+        return jax.lax.dynamic_update_slice_in_dim(acc, new, start, axis=1)
+
+    return jax.lax.fori_loop(0, jnp.sum(touched[:n_win]), one_window, leaf)
+
+
+def install_prefill_blocks(pool, dense, src, dst, *, seq_axis=None):
+    """Copy blocks ``src`` of a batch=1 dense cache into pool slots
+    ``dst`` (traced under ``PagedKVCache._install``, pool donated).
+
+    Dense leaves ``[L, 1, S, ...]`` are viewed in the pool's block layout
+    ``[L, S/BS, BS, ...]``; ``src``/``dst`` are int32 ``[n]``, and a
+    ``dst`` past the pool's last slot is a pad that writes nothing.
+
+    XLA keeps a pool leaf on the TPU with its block axis minor, so a
+    scatter of whole blocks relayouts the whole pool, twice, and a
+    per-block update writes one lane of every tile it touches. The
+    install works in windows of blocks instead (``_install_windows``);
+    the blocks a row claims together are mostly adjacent, so an install
+    touches a window or two. Under the kv-sequence split (``seq_axis``,
+    inside the pool's ``shard_map``) each rank installs the blocks of
+    its own shard."""
+    out = {}
+    for name, leaf in pool.items():
+        d = dense[name]
+        L, _, S = d.shape[:3]
+        bs = leaf.shape[2]
+        blocks = d.reshape((L, S // bs, bs) + d.shape[3:])
+        payload = jnp.take(blocks, src, axis=1, mode="clip").astype(leaf.dtype)
+        local = dst if seq_axis is None else dst - jax.lax.axis_index(seq_axis) * leaf.shape[1]
+        out[name] = _install_windows(leaf, payload, local)
+    return out
 
 
 class SlotKVCache:
@@ -236,9 +315,16 @@ class PagedKVCache:
         # donation keeps every subsequent new_pool on the same
         # NamedSharding, so KV bytes never migrate between ranks.
         # Tables/lengths stay host-side numpy (they are data, replicated
-        # on upload by the step's in_specs).
+        # on upload by the step's in_specs). One program installs every
+        # prefill (write_prefill), the pool donated; under a mesh each
+        # rank installs into its own pool shard, and the result is pinned
+        # to the pool's shardings so the donated leaves stay where they
+        # were.
+        install, out_shardings = install_prefill_blocks, None
         if mesh is not None:
+            from jax import shard_map
             from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
 
             tp = int(mesh.shape.get("model", 1))
             specs = model.paged_pool_specs(
@@ -248,6 +334,16 @@ class PagedKVCache:
                 name: jax.device_put(leaf, NamedSharding(mesh, specs[name]))
                 for name, leaf in self.pool.items()
             }
+            dense_specs = {name: P(spec[0], None, *spec[2:]) for name, spec in specs.items()}
+            install = shard_map(
+                functools.partial(install_prefill_blocks, seq_axis="seq" if sp > 1 else None),
+                mesh=mesh,
+                in_specs=(specs, dense_specs, P(), P()),
+                out_specs=specs,
+                check_vma=False,
+            )
+            out_shardings = {name: leaf.sharding for name, leaf in self.pool.items()}
+        self._install = jax.jit(install, donate_argnums=0, out_shardings=out_shardings)
         cfg = model.cfg
         # PREFIX_FAMILIES lives next to the model's prefill_with_prefix,
         # which enforces the same exclusions — the two layers can't
@@ -458,31 +554,25 @@ class PagedKVCache:
         """Install a request's batch=1 dense prefill cache into its fresh
         prompt blocks. ``skip_blocks`` leading blocks are a prefix hit —
         already in the pool, shared, and immutable, so they are not
-        rewritten."""
+        rewritten. The copy runs on the device (``install_prefill_blocks``,
+        the pool donated) and the host never waits for it."""
         if self._row_owner[row] is None:
             raise RuntimeError(f"write into free row {row}")
-        bs = self.block_size
         n_prompt = self.blocks_for(int(self.cache_len[row]))
         ids = self._row_blocks[row][skip_blocks:n_prompt]
         if not ids:
             return
-        # one fixed-shape scatter per pool leaf: the index vector is
-        # padded to blocks_per_row by repeating the last block id with
-        # its own (identical) payload, so every install — any prompt
-        # length, any prefix-hit skip — reuses the same compiled op
-        # instead of paying an eager compile per (skip, n) combination
-        pad = self.blocks_per_row - len(ids)
-        idx = jnp.asarray(self._slot[np.array(list(ids) + [ids[-1]] * pad, np.int32)])
-        for name, leaf in self.pool.items():
-            d = np.asarray(dense_cache[name])  # [L, 1, S_dense, ...]
-            L, _, Sd = d.shape[:3]
-            blocks = d.reshape((L, Sd // bs, bs) + d.shape[3:])
-            src = blocks[:, skip_blocks:n_prompt]
-            if pad:
-                src = np.concatenate(
-                    [src, np.repeat(src[:, -1:], pad, axis=1)], axis=1
-                )
-            self.pool[name] = leaf.at[:, idx].set(jnp.asarray(src.astype(leaf.dtype)))
+        # fixed-length block vectors, so every install — any prompt
+        # length, any prefix-hit skip — runs the one compiled program:
+        # pad entries read dense block 0 and point past the pool's end,
+        # so they write nothing
+        src = np.zeros(self.blocks_per_row, np.int32)
+        dst = np.full(self.blocks_per_row, self.total_blocks, np.int32)
+        src[: len(ids)] = np.arange(skip_blocks, n_prompt)
+        dst[: len(ids)] = self._slot[ids]
+        src, dst = jax.device_put((src, dst))
+        dense = {name: dense_cache[name] for name in self.pool}
+        self.pool = self._install(self.pool, dense, src, dst)
 
     def ensure_tail(self, row: int) -> None:
         """Make sure the row's next decode write position has a physical

@@ -841,8 +841,7 @@ class Scheduler:
                 kw["prompt_len"] = jnp.asarray([S], jnp.int32)
             with self.tel.span("serve.prefill"):
                 logits, cache = self._prefill(self.params, prompt_dev[None, :], **kw)
-        with self.tel.span("serve.write_prefill"):
-            self.kv.write_prefill(row, cache, skip_blocks=len(hit_ids))
+        self._write_prefill(row, cache, len(hit_ids))
         if resume:
             self._resume_decode(req, row, now)
         else:
@@ -1125,6 +1124,13 @@ class Scheduler:
             self._s_chunk_util.append((self.chunk_size - budget) / self.chunk_size)
         return True
 
+    def _write_prefill(self, row: int, cache, skip_blocks: int) -> None:
+        """``kv.write_prefill`` under its span, whose ``blocks`` arg is the
+        number of fresh blocks the install writes."""
+        fresh = self.kv.blocks_for(int(self.kv.cache_len[row])) - skip_blocks
+        with self.tel.span("serve.write_prefill", blocks=fresh):
+            self.kv.write_prefill(row, cache, skip_blocks=skip_blocks)
+
     def _install_chunked(self, st: _ChunkState, row: int, logits_row, now) -> None:
         """A fully-chunked prompt lands in the pool: paged rows write
         their fresh blocks (prefix-hit blocks skipped — already shared
@@ -1132,8 +1138,7 @@ class Scheduler:
         blocks hold real KV; slotted rows install the whole dense cache."""
         req = st.req
         if self.kv_layout == "paged":
-            with self.tel.span("serve.write_prefill"):
-                self.kv.write_prefill(row, st.cache, skip_blocks=st.skip_blocks)
+            self._write_prefill(row, st.cache, st.skip_blocks)
             self.kv.register_prompt(row, tuple(int(t) for t in st.prompt))
         else:
             self.kv.write(row, st.cache)

@@ -371,6 +371,136 @@ def test_any_mutation_refreshes_kernel_inputs(seed):
         kv.check_invariants()
 
 
+# ---------------------------------------------------------------------------
+# write_prefill: the device install against the numpy install it replaced
+
+
+def _host_install(kv, pool, row, dense, skip_blocks):
+    """The numpy install ``write_prefill`` used to run (dense cache to
+    the host, slice, pad by repeating the last block with its own
+    payload, scatter): the bits the device program must reproduce."""
+    bs = kv.block_size
+    n_prompt = kv.blocks_for(int(kv.cache_len[row]))
+    ids = kv._row_blocks[row][skip_blocks:n_prompt]
+    out = {name: np.array(leaf) for name, leaf in pool.items()}
+    if not ids:
+        return out
+    pad = kv.blocks_per_row - len(ids)
+    idx = kv._slot[np.array(list(ids) + [ids[-1]] * pad, np.int32)]
+    for name, leaf in out.items():
+        d = np.asarray(dense[name])
+        L, _, Sd = d.shape[:3]
+        src = d.reshape((L, Sd // bs, bs) + d.shape[3:])[:, skip_blocks:n_prompt]
+        src = np.concatenate([src, np.repeat(src[:, -1:], pad, axis=1)], axis=1)
+        leaf[:, idx] = src.astype(leaf.dtype)
+    return out
+
+
+def _random_like(tree, rng):
+    """Random values in each leaf's shape and dtype (int8 leaves over
+    their whole range), so a misplaced or missing write cannot hide
+    behind zeros."""
+    def one(leaf):
+        if jnp.issubdtype(leaf.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(-128, 128, leaf.shape).astype(leaf.dtype))
+        return jnp.asarray(rng.standard_normal(leaf.shape), leaf.dtype)
+    return {name: one(leaf) for name, leaf in tree.items()}
+
+
+_INSTALL_CASES = ["boundary", "mid_block", "prefix_hit", "straddle", "pool_end"]
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["", "int8"])
+@pytest.mark.parametrize("case", _INSTALL_CASES)
+def test_device_install_matches_host_install(case, kv_quant):
+    """Every pool leaf after ``write_prefill`` equals, bit for bit, what
+    the numpy host install made of the same pool: the fresh blocks hold
+    the dense cache's blocks, and the shared prefix blocks of a hit,
+    other rows' blocks, the null block and the spare slot are untouched
+    (padded entries write nothing). Prompts end on a block boundary and
+    mid-block; int8-KV pools install four leaves. In a pool of more than
+    one install window, the row's blocks straddle two windows, or sit in
+    the last window, which overlaps the one before it."""
+    import dataclasses
+
+    from repro.serve import kv_cache
+
+    cfg = dataclasses.replace(get_config("smollm-135m").reduced(), kv_quant=kv_quant)
+    m = Model(cfg)
+    bs, max_seq = 4, 32
+    rng = np.random.default_rng(7)
+    big = case in ("straddle", "pool_end")
+    kv = PagedKVCache(m, max_batch=3, max_seq=max_seq, block_size=bs,
+                      num_blocks=300 if big else 20)
+    kv.pool = _random_like(kv.pool, rng)
+    if big:  # the allocator hands out the lowest free ids: skip past some
+        for _ in range(123 if case == "straddle" else 280):
+            kv.allocator.alloc()
+    assert len(kv.pool) == (4 if kv_quant else 2)
+    # another row holds data first, so its blocks must survive the install
+    other = tuple(range(100, 100 + 3 * bs))
+    r0, _ = kv.try_admit(0, other, budget=4)
+    kv.write_prefill(r0, _random_like(m.init_cache(1, max_seq), rng))
+    plen = {"mid_block": 3 * bs + 3, "prefix_hit": 3 * bs + 2}.get(case, 3 * bs)
+    prompt = tuple(range(1, plen + 1))
+    if case == "prefix_hit":
+        ra, _ = kv.try_admit(1, prompt[: 2 * bs] + (999,) * bs, budget=4)
+        kv.write_prefill(ra, _random_like(m.init_cache(1, max_seq), rng))
+    row, hit = kv.try_admit(2, prompt, budget=4)
+    skip = len(hit)
+    assert skip == (2 if case == "prefix_hit" else 0)
+    dense = _random_like(m.init_cache(1, max_seq), rng)
+    want = _host_install(kv, kv.pool, row, dense, skip)
+    before = {name: np.array(leaf) for name, leaf in kv.pool.items()}
+    kv.write_prefill(row, dense, skip_blocks=skip)
+    fresh = kv._slot[kv._row_blocks[row][skip:]]
+    if case == "straddle":
+        assert fresh.min() < kv_cache.INSTALL_WINDOW <= fresh.max()
+    if case == "pool_end":
+        assert fresh.min() >= 2 * kv_cache.INSTALL_WINDOW
+    for name, leaf in kv.pool.items():
+        got = np.asarray(leaf)
+        np.testing.assert_array_equal(got, want[name], err_msg=name)
+        untouched = np.setdiff1d(np.arange(kv.total_blocks), fresh)
+        np.testing.assert_array_equal(got[:, untouched], before[name][:, untouched])
+        assert not np.array_equal(got[:, fresh], before[name][:, fresh]), name
+
+
+class _NumpyRefusingDeviceArrays:
+    """``numpy`` as ``serve/kv_cache.py`` sees it, refusing to convert a
+    ``jax.Array``: the CPU backend does not enforce the transfer guard."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        assert not isinstance(a, jax.Array), "device-to-host copy"
+        return np.asarray(a, *args, **kw)
+
+    array = asarray
+
+
+def test_device_install_no_host_trip_one_compile(monkeypatch):
+    """``write_prefill`` never copies device data to the host, and one
+    compiled program serves every prompt length and prefix-hit skip."""
+    from repro.serve import kv_cache
+
+    m, dense = _mutation_fixture()  # prefill cache over max_seq 16
+    kv = PagedKVCache(m, max_batch=4, max_seq=16, block_size=4, num_blocks=24)
+    kv._install.clear_cache()  # jit wrappers of one function share a cache
+    monkeypatch.setattr(kv_cache, "np", _NumpyRefusingDeviceArrays())
+    with jax.transfer_guard_device_to_host("disallow"):
+        for rid, plen in enumerate((3, 4, 9, 16)):
+            row, hit = kv.try_admit(rid, tuple(range(plen)), budget=0)
+            kv.write_prefill(row, dense, skip_blocks=len(hit))
+        # a prefix hit over two blocks of rid 2's prompt
+        kv.free_row(0)
+        row, hit = kv.try_admit(9, tuple(range(8)) + (40, 41, 42), budget=0)
+        assert len(hit) == 2
+        kv.write_prefill(row, dense, skip_blocks=len(hit))
+    assert kv._install._cache_size() == 1
+
+
 def test_paged_cache_rejects_non_attention_family():
     cfg = get_config("mamba2-370m").reduced()
     m = Model(cfg)

@@ -208,6 +208,40 @@ print("PASS")
 """)
 
 
+@pytest.mark.parametrize(
+    "shape,names", [((2,), ("model",)), ((2,), ("seq",))], ids=["model2", "seq2"]
+)
+def test_sharded_install_keeps_pool_sharding(shape, names):
+    """``write_prefill`` on a head-split or seq-split pool returns every
+    leaf on the sharding it had (the install donates the pool, pinned to
+    its shardings), and the rows it wrote, gathered through each pool's
+    slot map, equal the unsharded install's."""
+    _run(_mesh_header(shape, names) + """
+from repro.serve.kv_cache import PagedKVCache
+m, params = build(CFG)
+prompts = [(np.arange(n, dtype=np.int32) * 3 + 1) % cfg_vocab for n in (5, 8, 13)]
+prefill = jax.jit(lambda pr: m.prefill(params, pr, 32))
+dense = [prefill(jnp.asarray(p)[None])[1] for p in prompts]
+def install(use_mesh):
+    kv = PagedKVCache(m, max_batch=4, max_seq=32, block_size=4,
+                      mesh=mesh if use_mesh else None)
+    before = {name: leaf.sharding for name, leaf in kv.pool.items()}
+    assert not use_mesh or any(a is not None for a in before["k"].spec)
+    for i, (p, d) in enumerate(zip(prompts, dense)):
+        row, hit = kv.try_admit(i, p, budget=4)
+        kv.write_prefill(row, d, skip_blocks=len(hit))
+        for name, leaf in kv.pool.items():
+            assert leaf.sharding == before[name], (name, leaf.sharding, before[name])
+    ids = kv._slot[np.arange(kv.num_blocks + 1)]
+    return {name: np.asarray(leaf)[:, ids] for name, leaf in kv.pool.items()}
+base, got = install(False), install(True)
+for name in base:
+    np.testing.assert_array_equal(got[name], base[name], err_msg=name)
+    assert np.abs(base[name].astype(np.float32)).sum() > 0
+print("PASS")
+""")
+
+
 def test_mesh_fallback_warns_once():
     """GQA fallback dedupe: serving repeatedly through a mesh the head
     partitioning cannot divide warns exactly once per (cfg, mesh), keeps
