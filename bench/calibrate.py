@@ -15,8 +15,9 @@ the highest rate whose backlog does not grow over the window; a cell's
 
 ``gaps`` serves a window per seed, with that seed's weights and
 traffic, and prints the widest logit gap of what the program served
-(``reference.judge``) beside the control's: the fp8 reference's gap at
-the same positions. A cell's ``limits`` are set from these readings.
+(against the reference of the configuration's block) beside the
+control's: the fp8 reference's gap at the same positions. A cell's ``limits`` are set from
+these readings.
 """
 from __future__ import annotations
 
@@ -28,7 +29,7 @@ import time
 
 import numpy as np
 
-from bench import reference, run, spec, weights
+from bench import compare, run, spec, weights
 
 
 def backlog_at(recs, t: float) -> int:
@@ -81,11 +82,14 @@ def main(argv=None) -> int:
     peaks = spec.load_peaks(dev.device_kind)
     run.use_compile_cache()
     seeds = args.seeds or [args.seed]
-    engine, sizes = run.build(cell, conf, seeds[0])
+    engine, sizes, block = run.build(cell, conf, seeds[0])
     counter = run.CompileCounter()
 
     def window(seed, rate=None):
-        ctx = run.Ctx(cell=cell, config=conf, mix=mix, sizes=sizes, peaks=peaks, seconds=args.seconds)
+        ctx = run.Ctx(
+            cell=cell, config=conf, mix=mix, sizes=sizes, peaks=peaks, block=block,
+            seconds=args.seconds,
+        )
         ctx.backlog = mix["kind"] == "backlog"
         counter.seen.clear()
         submitted = run.serve(engine, ctx, seed=seed, rate=rate, trace=False, counter=counter)
@@ -106,14 +110,14 @@ def main(argv=None) -> int:
         if i:
             engine.params = None
             gc.collect()
-            engine.params = weights.make(engine.model.abstract_params()[0], sizes, seed)
+            engine.params = weights.make(engine.model.abstract_params()[0], sizes, seed, block)
         ctx, submitted = window(seed)
         sample = run.sample_of(cell, submitted, seed)
         del submitted
         gc.collect()
         t = time.perf_counter()
-        v = reference.judge(
-            engine.params, sample, sizes=sizes, max_seq=int(cell["max_seq"]),
+        v = compare.judge(
+            block.logits, engine.params, sample, sizes=sizes, max_seq=int(cell["max_seq"]),
             window=int(mix["output"]["hi"]), control=True,
         )
         v.update(

@@ -1,12 +1,12 @@
 """The paged decode attention kernel's share of its roofline, in %: the
 least time the chip could take for the traced decode steps' attention
 (the larger of its FLOPs over the bf16 peak and its bytes over the HBM
-peak, ``flops.paged_attn_cost``: live K/V, q and o) over the kernel's
-device time in the trace. The bound that binds goes into the notes.
+peak, the block's ``paged_attn_cost``: live K/V, q and o) over the
+kernel's device time in the trace. The bound that binds goes into the notes.
 
 The kernel is the one Pallas call on the path: on the device's op line
 a TPU custom call (``_paged_attention_impl``), one per layer a step."""
-from bench import flops, trace
+from bench import trace
 
 
 def read(ctx):
@@ -18,7 +18,7 @@ def read(ctx):
         return None
     f = b = 0
     for _, _, c in calls:
-        df, db = flops.paged_attn_cost(ctx.sizes, c)
+        df, db = ctx.block.paged_attn_cost(ctx.sizes, c)
         f, b = f + df, b + db
     t_flops = f / ctx.peaks["bf16_flops_per_s"]
     t_bytes = b / ctx.peaks["hbm_bytes_per_s"]

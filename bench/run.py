@@ -9,7 +9,8 @@ reaches compiled or read from the compile cache at
 requests arrive open-loop on the harness's clock, let the requests due
 in the window finish (``drain_s`` at most, rate cells only), read the
 device's peak memory, free the program's state, and judge what it
-served against the plain reference (``reference.py``).
+served against the plain reference of the configuration's block
+(``blocks/<block>.py``, found by ``spec.load_block``).
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end
 metrics from ``BENCHMARK.json``; with ``--trace 1`` its per-layer
@@ -40,7 +41,7 @@ from contextlib import nullcontext  # noqa: E402
 
 import numpy as np  # noqa: E402
 
-from bench import reference, spec, traffic, weights  # noqa: E402
+from bench import compare, spec, traffic, weights  # noqa: E402
 from bench import trace as trace_mod  # noqa: E402
 
 spec.use_program()
@@ -63,6 +64,7 @@ class Ctx:
     mix: dict
     sizes: dict
     peaks: dict
+    block: object  # the configuration's block module (spec.load_block)
     seconds: float  # the window asked for
     elapsed: float = 0.0  # the window as run (its last step ends it)
     setup_s: float = 0.0
@@ -119,30 +121,19 @@ def use_compile_cache() -> None:
 
 def program_config(conf: dict):
     """The program's config for a configuration file, checked against the
-    file's published sizes."""
+    file's published sizes as its block reads them. Returns (program
+    config, sizes, block)."""
     spec.use_program()
     from repro.configs import get_config
 
+    block = spec.load_block(conf)
     pcfg = dataclasses.replace(get_config(conf["registry"]), **conf.get("overrides", {}))
-    sizes = reference.sizes_from_config(conf)
-    want = {
-        "num_layers": sizes["layers"],
-        "d_model": sizes["d_model"],
-        "n_heads": sizes["n_heads"],
-        "n_kv_heads": sizes["n_kv_heads"],
-        "head_dim": sizes["head_dim"],
-        "d_ff": sizes["d_ff"],
-        "vocab_size": sizes["vocab"],
-        "tie_embeddings": sizes["tied"],
-        "norm_eps": sizes["eps"],
-        "rope_theta": sizes["theta"],
-        "dtype": conf["torch_dtype"],
-        "family": "dense",
-    }
+    sizes = block.sizes(conf)
+    want = block.program_fields(sizes, conf)
     got = {k: getattr(pcfg, k) for k in want}
     if got != want:
         raise spec.SpecError(f"program config {pcfg.name} differs from {conf['name']}: {got} != {want}")
-    return pcfg, sizes
+    return pcfg, sizes, block
 
 
 class CompileCounter:
@@ -313,16 +304,16 @@ def device_memory_peak() -> int:
 def build(cell: dict, conf: dict, seed: int, *, trace: bool = False, backend: str = "kernel"):
     """The system under test: the program's model and serving engine at
     the cell's sizes, holding the weights the benchmark made from the
-    seed. Returns (engine, sizes)."""
+    seed. Returns (engine, sizes, block)."""
     import jax
 
     from repro.models.model import Model
     from repro.serve.engine import ServingEngine
     from repro.serve.telemetry import Telemetry
 
-    pcfg, sizes = program_config(conf)
+    pcfg, sizes, block = program_config(conf)
     model = Model(pcfg)
-    params = weights.make(model.abstract_params()[0], sizes, seed)
+    params = weights.make(model.abstract_params()[0], sizes, seed, block)
     jax.block_until_ready(params)
     tel = Telemetry(enabled=True, xla_annotations=True) if trace else None
     engine = ServingEngine(
@@ -330,7 +321,7 @@ def build(cell: dict, conf: dict, seed: int, *, trace: bool = False, backend: st
         block_size=int(cell["block_size"]), num_blocks=cell.get("num_blocks"),
         prefix_cache=True, attention_backend=backend, telemetry=tel,
     )
-    return engine, sizes
+    return engine, sizes, block
 
 
 def serve(engine, ctx: Ctx, *, seed: int, rate=None, trace: bool, counter, break_program=None):
@@ -387,8 +378,11 @@ def run_cell(
     import jax
 
     counter = CompileCounter()
-    engine, sizes = build(cell, conf, seed, trace=trace, backend=backend)
-    ctx = Ctx(cell=cell, config=conf, mix=mix, sizes=sizes, peaks=peaks, seconds=float(seconds))
+    engine, sizes, block = build(cell, conf, seed, trace=trace, backend=backend)
+    ctx = Ctx(
+        cell=cell, config=conf, mix=mix, sizes=sizes, peaks=peaks, block=block,
+        seconds=float(seconds),
+    )
     ctx.backlog = mix["kind"] == "backlog"
     submitted = serve(
         engine, ctx, seed=seed, trace=trace, counter=counter, break_program=break_program
@@ -410,8 +404,8 @@ def run_cell(
             result_metrics[m["name"]] = {"value": float(val), "unit": m["unit"]}
 
     t_check = time.perf_counter()
-    verdict = reference.judge(
-        params, sample, sizes=sizes, max_seq=int(cell["max_seq"]),
+    verdict = compare.judge(
+        block.logits, params, sample, sizes=sizes, max_seq=int(cell["max_seq"]),
         window=int(mix["output"]["hi"]),
     )
     verdict["check_s"] = time.perf_counter() - t_check

@@ -6,7 +6,10 @@ so adding one never edits another:
 - ``cells/<cell>.json``: the configuration, the mix, the engine's sizes
   and the offered rate;
 - ``configs/<config>.json``: the published sizes, the registry name the
-  program knows them by, and what was changed or assumed;
+  program knows them by, what was changed or assumed, and the ``block``
+  that reads them (``llama`` where the file names none);
+- ``blocks/<block>.py``: an architecture's sizes, program fields, weight
+  rule, plain reference and counts (``blocks/__init__.py``);
 - ``traffic/<mix>.json``: the generator's parameters;
 - ``metrics/<metric>.py``: one ``read(ctx)`` per metric;
 - ``peaks.json``: the chip's peaks, keyed by ``device_kind``.
@@ -15,6 +18,7 @@ from __future__ import annotations
 
 import importlib
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -53,6 +57,33 @@ def load_config(name: str) -> dict:
     cfg = _load_json(HERE / "configs" / f"{name}.json")
     cfg["name"] = name
     return cfg
+
+
+DEFAULT_BLOCK = "llama"
+BLOCK_API = (
+    "sizes", "program_fields", "ONES", "fan_in", "logits", "decode_flops", "paged_attn_cost",
+)
+
+
+def load_block(conf: dict):
+    """The block module ``blocks/<name>.py`` that the configuration names
+    (``"block": "<name>"``; ``llama`` where it names none)."""
+    name = conf.get("block", DEFAULT_BLOCK)
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_]+", name):
+        raise SpecError(f"config {conf.get('name')!r}: bad block name {name!r}")
+    module = f"bench.blocks.{name}"
+    try:
+        block = importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise SpecError(
+            f"config {conf.get('name')!r}: no block {name!r} (bench/blocks/{name}.py)"
+        ) from None
+    missing = [a for a in BLOCK_API if not hasattr(block, a)]
+    if missing:
+        raise SpecError(f"block {name!r} lacks {missing}")
+    return block
 
 
 def load_mix(name: str) -> dict:

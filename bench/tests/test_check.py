@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, run, weights
+from bench import compare, run, weights
+from bench.blocks import llama
 from bench.tests import tiny
 
 # Tiny-size readings (CPU, seeds 1-6): the program's widest gap
@@ -30,11 +31,13 @@ def test_program_is_correct():
 
 def test_control_fails_the_limit():
     cell, conf, mix = tiny.cell(LIMIT), tiny.conf(), tiny.mix()
-    engine, sizes = run.build(cell, conf, 12, backend="interpret")
-    ctx = run.Ctx(cell=cell, config=conf, mix=mix, sizes=sizes, peaks=tiny.PEAKS, seconds=1.5)
+    engine, sizes, block = run.build(cell, conf, 12, backend="interpret")
+    ctx = run.Ctx(
+        cell=cell, config=conf, mix=mix, sizes=sizes, peaks=tiny.PEAKS, block=block, seconds=1.5
+    )
     sub = run.serve(engine, ctx, seed=12, trace=False, counter=run.CompileCounter())
-    v = reference.judge(
-        engine.params, run.sample_of(cell, sub, 12), sizes=sizes, max_seq=64,
+    v = compare.judge(
+        block.logits, engine.params, run.sample_of(cell, sub, 12), sizes=sizes, max_seq=64,
         window=16, control=True,
     )
     assert v["max_logit_gap"] <= LIMIT < v["control_max_logit_gap"]
@@ -62,7 +65,7 @@ def test_fault_is_not_correct(fault):
 def test_weights_from_large_seeds_differ():
     shapes = {"embed": jnp.zeros((8, 4), jnp.bfloat16)}
     sizes = {"d_model": 4, "vocab": 6, "d_ff": 8, "n_heads": 1, "head_dim": 4}
-    a = np.asarray(weights.make(shapes, sizes, 2**31 + 5)["embed"], np.float32)
-    b = np.asarray(weights.make(shapes, sizes, 2**31 + 6)["embed"], np.float32)
+    a = np.asarray(weights.make(shapes, sizes, 2**31 + 5, llama)["embed"], np.float32)
+    b = np.asarray(weights.make(shapes, sizes, 2**31 + 6, llama)["embed"], np.float32)
     assert not np.array_equal(a, b)
     assert np.all(a[6:] == 0)  # pad rows past the published vocabulary

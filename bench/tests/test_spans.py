@@ -84,7 +84,7 @@ def test_program_spans_in_a_recorded_trace(tmp_path):
     from repro.serve.request import Request
 
     cell = tiny.cell()
-    engine, _ = run.build(cell, tiny.conf(), 5, trace=True, backend="interpret")
+    engine, *_ = run.build(cell, tiny.conf(), 5, trace=True, backend="interpret")
     rng = np.random.default_rng(5)
 
     def reqs():

@@ -5,7 +5,8 @@ import re
 
 import pytest
 
-from bench import reference, spec
+from bench import spec
+from bench.blocks import llama, stablelm
 
 BENCH = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -38,7 +39,7 @@ def test_config_file_matches_its_entry(conf):
     assert conf["file"] == f"bench/configs/{conf['name']}.json"
     assert data["source"] == conf["source"]
     assert data["reduced"] == conf["reduced"]
-    sizes = reference.sizes_from_config(data)
+    sizes = spec.load_block(data).sizes(data)
     assert sizes["d_model"] % sizes["n_heads"] == 0
     assert any(w["config"] == conf["name"] for w in BENCH["workloads"])
 
@@ -76,3 +77,12 @@ def test_unknown_names_are_refused():
         spec.load_cell("no-such.cell")
     with pytest.raises(spec.SpecError):
         spec.metric_reader("no_such_metric")
+
+
+def test_block_lookup():
+    assert spec.load_block(spec.load_config("smollm-135m")) is llama  # no "block" key
+    assert spec.load_block(spec.load_config("stablelm-3b")) is stablelm
+    assert spec.load_block({"name": "x", "block": "llama"}) is llama
+    for bad in ("no_such_block", "../llama", "llama.x", "", 3):
+        with pytest.raises(spec.SpecError):
+            spec.load_block({"name": "x", "block": bad})

@@ -103,3 +103,21 @@ def test_load_a_recorded_trace(tmp_path):
     assert "/host:CPU" in tr.layout
     # a CPU holds no device plane: nothing ran on a device
     assert trace.device_busy_s(tr) == 0.0
+
+
+def test_install_device_ms():
+    from bench.metrics import install_device_ms
+
+    class Ctx:
+        trace = hand_trace()
+
+    # no install program in the window: nothing to read
+    assert install_device_ms.read(Ctx) is None
+    Ctx.trace.modules[0] += [
+        Ev("jit_install_prefill_blocks(4)", 8.2 * MS, 8.6 * MS),
+        Ev("jit_install_prefill_blocks(4)", 9.0 * MS, 9.2 * MS),
+        Ev("jit_install_prefill_blocks(4)", 10.5 * MS, 11.5 * MS),  # after the window
+    ]
+    assert install_device_ms.read(Ctx) == pytest.approx(0.3)
+    Ctx.trace = None
+    assert install_device_ms.read(Ctx) is None

@@ -5,15 +5,13 @@ them as it would be handed a checkpoint, and the plain reference reads
 the same arrays. The tree has the layout the program's ``Model.init``
 returns (its shapes are read from ``jax.eval_shape``; nothing is
 computed by the program). Each leaf is drawn in the type it is served
-in: N(0, fan_in^-0.5) for the projections and the embedding, ones for
-the norm scales, zeros for the vocabulary's pad rows.
+in, by the configuration's block's rule (``ONES``, ``fan_in``): ones, or
+N(0, fan_in^-0.5); the vocabulary's pad rows are zeros.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-NORMS = ("final_norm", "attn_norm", "mlp_norm", "norm")
 
 
 def seed_key(seed: int):
@@ -26,20 +24,12 @@ def _path_name(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
-def _fan_in(name: str, sizes: dict) -> int:
-    leaf = name.rsplit("/", 1)[-1]
-    if leaf == "wo":
-        return sizes["n_heads"] * sizes["head_dim"]
-    if leaf == "w2":
-        return sizes["d_ff"]
-    return sizes["d_model"]
+def make(shapes, sizes: dict, seed: int, block):
+    """Fill the tree of ``jax.ShapeDtypeStruct`` ``shapes`` from ``seed``
+    by ``block``'s weight rule.
 
-
-def make(shapes, sizes: dict, seed: int):
-    """Fill the tree of ``jax.ShapeDtypeStruct`` ``shapes`` from ``seed``.
-
-    ``sizes`` gives ``d_model``, ``d_ff``, ``n_heads``, ``head_dim`` and
-    ``vocab`` (the published vocabulary: rows past it are pad)."""
+    ``sizes`` is the block's, with ``vocab`` (the published vocabulary:
+    rows past it are pad)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     names = [_path_name(p) for p, _ in flat]
     structs = [s for _, s in flat]
@@ -48,12 +38,12 @@ def make(shapes, sizes: dict, seed: int):
         out = []
         for i, (name, s) in enumerate(zip(names, structs)):
             leaf = name.rsplit("/", 1)[-1]
-            if leaf in NORMS:
+            if leaf in block.ONES:
                 out.append(jnp.ones(s.shape, s.dtype))
                 continue
             k = jax.random.fold_in(key, i)
             w = jax.random.normal(k, s.shape, s.dtype)
-            w = w * jnp.asarray(_fan_in(name, sizes) ** -0.5, s.dtype)
+            w = w * jnp.asarray(block.fan_in(name, sizes) ** -0.5, s.dtype)
             if leaf == "embed":
                 rows = jnp.arange(s.shape[0])[:, None] < sizes["vocab"]
                 w = jnp.where(rows, w, jnp.zeros((), s.dtype))
