@@ -173,12 +173,17 @@ def _attention_microbench(backends, reps: int = 20, seed: int = 0) -> dict:
     import jax.numpy as jnp
 
     from repro.kernels import ops
+    from repro.models.attention import to_stored, tokens_per_row
 
     rng = np.random.default_rng(seed)
     B, T, KV, g, hd, NB, BS, MB = 4, 1, 2, 2, 32, 64, 16, 8
     q = jnp.asarray(rng.normal(size=(B, T, KV * g, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(NB, BS, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(NB, BS, KV, hd)), jnp.float32)
+    # one layer of a pool, stored as the kernel reads it
+    pool = lambda: to_stored(
+        jnp.asarray(rng.normal(size=(1, NB, BS, KV, hd)), jnp.float32),
+        tokens_per_row(BS, hd),
+    )
+    kp, vp = pool(), pool()
     tbl = jnp.asarray(rng.integers(0, NB, size=(B, MB)), jnp.int32)
     lens = jnp.asarray(rng.integers(BS, MB * BS - 1, size=(B,)), jnp.int32)
     out = {}

@@ -260,7 +260,11 @@ def resident(engine, workload, seed: int):
 def score(engine, backend: str, inputs):
     """Logits of one paged decode (T=1) and one paged verify (T=K+1)
     through ``engine``'s step family for ``backend`` (sharded over its
-    mesh, if it has one), rows in request order."""
+    mesh, if it has one), rows in request order. The steps take the
+    pool donated, so each call is handed its own copy of ``inputs``'
+    pool, which stays live for the next."""
+    import jax
+    import jax.numpy as jnp
     import numpy as np
 
     pool, tables, lens, toks, rows = inputs
@@ -271,7 +275,7 @@ def score(engine, backend: str, inputs):
             model.jit_step(name, backend) if mesh is None
             else model.sharded_paged_step(name, mesh, backend)
         )
-        logits, _ = step(engine.params, pool, tables, lens, tok)
+        logits, _ = step(engine.params, jax.tree.map(jnp.copy, pool), tables, lens, tok)
         out.append(np.asarray(logits, np.float32)[rows])
     return out
 

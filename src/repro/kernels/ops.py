@@ -189,23 +189,24 @@ def check_vmem(block_bytes: dict[str, int], buffering: int = 2) -> None:
 
 def _paged_blocks(q, k_pool, v_pool, k_scale) -> dict[str, int]:
     """VMEM bytes per buffer of the paged kernel's block schedule: one
-    whole pool block ``[BS, KV, hd]`` per K/V step (every kv group), the
-    ``[KV, T·g, hd]`` query block, output block (counted at f32, its
-    partials-mode dtype) and f32 accumulator, and the per-group
-    ``[T·g, BS]`` score tile."""
+    stored pool block ``[KV, R, W]`` per K/V step (every kv head), the
+    ``[KV, P, T·g, W]`` lane-slotted query block, output block (counted
+    at f32, its partials-mode dtype), the f32 ``[KV, T·g, W]``
+    accumulator, and the per-group ``[T·g, BS]`` scores."""
     T, H, hd = q.shape[1], q.shape[2], q.shape[3]
-    BS, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, R, W = k_pool.shape[2:]
+    P = W // hd
     rows = T * (H // KV)
     blocks = {
-        "q": KV * rows * hd * jnp.dtype(q.dtype).itemsize,
+        "q": KV * P * rows * W * jnp.dtype(q.dtype).itemsize,
         "o": KV * rows * hd * 4,
-        "k": BS * KV * hd * jnp.dtype(k_pool.dtype).itemsize,
-        "v": BS * KV * hd * jnp.dtype(v_pool.dtype).itemsize,
-        "acc": KV * rows * hd * 4,
-        "s": rows * BS * 4,
+        "k": KV * R * W * jnp.dtype(k_pool.dtype).itemsize,
+        "v": KV * R * W * jnp.dtype(v_pool.dtype).itemsize,
+        "acc": KV * rows * W * 4,
+        "s": rows * R * P * 4,
     }
     if k_scale is not None:
-        blocks["scales"] = 2 * BS * KV * jnp.dtype(k_scale.dtype).itemsize
+        blocks["scales"] = 2 * KV * R * P * jnp.dtype(k_scale.dtype).itemsize
     return blocks
 
 
@@ -268,19 +269,22 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, bk=512, mode="auto"):
 
 
 def paged_attention(
-    q, k_pool, v_pool, block_tables, lengths, k_scale=None, v_scale=None, *, mode="auto"
+    q, k_pool, v_pool, block_tables, lengths, k_scale=None, v_scale=None, *,
+    layer=0, mode="auto",
 ):
     """Block-paged decode/verify attention straight off the block pool.
 
     q [B,T,H,hd] (T static: 1 = decode, K+1 = speculative verify);
-    pools [NB,BS,KV,hd]; ``block_tables`` [B,MB] physical block ids per
-    decode row; ``lengths`` [B] committed lengths (query t attends
-    positions < lengths + t + 1). int8 pools pass per-vector
-    ``k_scale``/``v_scale`` [NB,BS,KV] and dequantize in-kernel. The
-    kernel walks the (scalar-prefetched) tables — no dense
-    ``gather_block_rows`` materialization; ``"ref"``/``"reference"`` is
-    the dense-gather oracle the differential tests compare against.
-    ``mode="auto"`` resolves through the ATTENTION registry
+    pools are the stored layer-stacked leaves [L,NB,KV,R,W]
+    (``models/attention.to_stored``), of which layer ``layer`` is read;
+    ``block_tables`` [B,MB] physical block ids per decode row;
+    ``lengths`` [B] committed lengths (query t attends positions <
+    lengths + t + 1). int8 pools pass per-token ``k_scale``/``v_scale``
+    [L,NB,KV,R,P] and dequantize in-kernel. The kernel walks the
+    (scalar-prefetched) tables — no dense ``gather_block_rows``
+    materialization; ``"ref"``/``"reference"`` is the dense-gather
+    oracle the differential tests compare against. ``mode="auto"``
+    resolves through the ATTENTION registry
     (``REPRO_ATTENTION_BACKEND``/``set_attention_backend``), not the
     generic ``REPRO_KERNEL_MODE`` — this is the serving-attention
     surface. Resolution happens here, OUTSIDE the jit boundary, so a
@@ -292,13 +296,13 @@ def paged_attention(
     if mode == "reference":
         mode = "ref"
     return _paged_attention_impl(
-        q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, mode=mode
+        q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, layer, mode=mode
     )
 
 
 def paged_attention_partials(
     q, k_pool, v_pool, block_tables, lengths, owned,
-    k_scale=None, v_scale=None, *, mode="auto",
+    k_scale=None, v_scale=None, *, layer=0, mode="auto",
 ):
     """Partials-emitting sibling of ``paged_attention`` for the
     kv-sequence-split serving path: same inputs on a LOCAL pool shard
@@ -320,7 +324,7 @@ def paged_attention_partials(
         )
     check_vmem(_paged_blocks(q, k_pool, v_pool, k_scale))
     return _paged_kernel(
-        q, k_pool, v_pool, block_tables, lengths,
+        q, k_pool, v_pool, block_tables, lengths, layer=layer,
         k_scale=k_scale, v_scale=v_scale, owned=owned, partials=True,
         interpret=mode == "interpret",
     )
@@ -328,15 +332,15 @@ def paged_attention_partials(
 
 @functools.partial(jax.jit, static_argnames=("mode",))
 def _paged_attention_impl(
-    q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, *, mode
+    q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, layer, *, mode
 ):
     if mode == "ref":
         return ref_ops.paged_attention_ref(
-            q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale
+            q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, layer
         )
     check_vmem(_paged_blocks(q, k_pool, v_pool, k_scale))
     return _paged_kernel(
-        q, k_pool, v_pool, block_tables, lengths,
+        q, k_pool, v_pool, block_tables, lengths, layer=layer,
         k_scale=k_scale, v_scale=v_scale, interpret=mode == "interpret",
     )
 

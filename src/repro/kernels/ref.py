@@ -41,29 +41,36 @@ def decode_attention_ref(q, k_cache, v_cache, cache_len):
     return jnp.einsum("bhs,bshd->bhd", p.astype(vc.dtype), vc)
 
 
-def paged_attention_ref(q, k_pool, v_pool, block_tables, lengths, k_scale=None, v_scale=None):
+def paged_attention_ref(
+    q, k_pool, v_pool, block_tables, lengths, k_scale=None, v_scale=None, layer=0
+):
     """Dense-gather oracle for the block-paged decode/verify kernel.
 
-    q [B,T,H,hd]; pools [NB,BS,KV,hd]; tables [B,MB]; lengths [B]
-    (query t attends positions < lengths + t + 1). int8 pools pass
-    per-vector scales [NB,BS,KV]."""
+    q [B,T,H,hd]; pools are stored layer-stacked leaves [L,NB,KV,R,W]
+    (token i of a block in row i // P, lanes (i % P)·hd on, P = W/hd),
+    of which layer ``layer`` is read; tables [B,MB]; lengths [B] (query
+    t attends positions < lengths + t + 1). int8 pools pass per-token
+    scales [L,NB,KV,R,P]."""
     B, T, H, hd = q.shape
-    BS, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, R, W = k_pool.shape[2:]
+    P = W // hd
     MB = block_tables.shape[1]
+    S = MB * R * P
     g = H // KV
-    kd = jnp.take(k_pool, block_tables, axis=0)  # [B, MB, BS, KV, hd]
-    vd = jnp.take(v_pool, block_tables, axis=0)
-    kd = kd.reshape(B, MB * BS, KV, hd).astype(jnp.float32)
-    vd = vd.reshape(B, MB * BS, KV, hd).astype(jnp.float32)
+
+    def dense(leaf, d):  # [B, MB·BS, KV, d] f32
+        x = jnp.take(jax.lax.dynamic_index_in_dim(leaf, layer, 0, False), block_tables, axis=0)
+        x = x.reshape(B, MB, KV, R * P, d).transpose(0, 1, 3, 2, 4)
+        return x.reshape(B, S, KV, d).astype(jnp.float32)
+
+    kd, vd = dense(k_pool, hd), dense(v_pool, hd)
     if k_scale is not None:
-        ks = jnp.take(k_scale, block_tables, axis=0).reshape(B, MB * BS, KV)
-        vs = jnp.take(v_scale, block_tables, axis=0).reshape(B, MB * BS, KV)
-        kd = kd * (ks.astype(jnp.float32) / 127.0)[..., None]
-        vd = vd * (vs.astype(jnp.float32) / 127.0)[..., None]
+        kd = kd * (dense(k_scale, 1) / 127.0)
+        vd = vd * (dense(v_scale, 1) / 127.0)
     kd = jnp.repeat(kd, g, axis=2)
     vd = jnp.repeat(vd, g, axis=2)
     s = jnp.einsum("bthd,bshd->bths", q.astype(jnp.float32), kd) / math.sqrt(hd)
-    pos = jnp.arange(MB * BS)[None, None, :]
+    pos = jnp.arange(S)[None, None, :]
     valid = pos < (lengths[:, None] + jnp.arange(T)[None, :] + 1)[:, :, None]
     s = jnp.where(valid[:, :, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)

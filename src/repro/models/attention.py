@@ -191,57 +191,138 @@ def scatter_token_flat(cache, new, cache_len):
     )(cache, new, cache_len)
 
 
-def gather_block_rows(pool_leaf, block_tables):
-    """Dense per-row cache view of a block-paged pool leaf.
+def tokens_per_row(block_size: int, head_dim: int) -> int:
+    """How many tokens of one kv head share a stored row of the paged
+    pool, ``P``. A pool leaf is stored ``[L, NB, KV, BS/P, P·hd]``: kv
+    head ``h`` of block ``n`` is one ``[BS/P, P·hd]`` tile (DESIGN.md
+    §4). Where ``hd`` divides 128 and that leaves the tile a whole
+    number of ``(8, 128)`` tiles, ``P = 128/hd``: the TPU then keeps the
+    leaf in the descending layout the paged kernel's BlockSpec reads,
+    unpadded, so a program takes the pool as it is stored. Otherwise
+    ``P = 1`` and the head's block is ``[BS, hd]`` as it comes."""
+    if head_dim < 128 and 128 % head_dim == 0:
+        p = 128 // head_dim
+        if block_size % (8 * p) == 0:
+            return p
+    return 1
 
-    ``pool_leaf`` [L, NB, BS, ...] (NB physical blocks of BS tokens);
-    ``block_tables`` [B, MB] int block ids per decode row. Returns
-    [L, B, MB·BS, ...] — each row's logical KV sequence gathered through
-    its block table. Fixed shape regardless of how many blocks a row
-    actually owns (unowned table entries point at the null block and are
-    masked off by ``cache_len`` in ``decode_attention``).
+
+def to_stored(x, p: int):
+    """Logical pool blocks ``[..., BS, KV, d]`` → the stored form
+    ``[..., KV, BS/p, p·d]`` (token ``i`` of a block in row ``i // p``,
+    lanes ``(i % p)·d`` on). A scale leaf passes ``[..., BS, KV, 1]``."""
+    *lead, bs, kv, d = x.shape
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, kv, bs // p, p * d)
+
+
+def to_logical(x, p: int):
+    """The stored form ``[..., KV, R, W]`` → logical pool blocks
+    ``[..., R·p, KV, W/p]``: the inverse of ``to_stored``."""
+    *lead, kv, r, w = x.shape
+    return jnp.swapaxes(x.reshape(*lead, kv, r * p, w // p), -3, -2)
+
+
+def is_scale(name: str) -> bool:
+    """Pool leaves named ``*_scale`` hold one number per token and head:
+    their logical blocks are ``[..., BS, KV]``, stored ``[..., KV, R, P]``."""
+    return name.endswith("_scale")
+
+
+def gather_block_rows(pool_leaf, block_tables, p: int):
+    """Dense per-row cache view of a stored block-paged pool leaf.
+
+    ``pool_leaf`` [L, NB, KV, R, W] (NB physical blocks of ``R·p``
+    tokens, stored ``p`` to a row); ``block_tables`` [B, MB] int block
+    ids per decode row. Returns [L, B, MB·BS, KV, W/p] — each row's
+    logical KV sequence gathered through its block table. Fixed shape
+    regardless of how many blocks a row actually owns (unowned table
+    entries point at the null block and are masked off by ``cache_len``
+    in ``decode_attention``).
 
     This dense materialization is the *reference/differential* path of
     the paged layout (and the prefix-reuse ``gather_prefix``); the
     serving hot path walks the tables inside the block-paged kernel
     instead (DESIGN.md §4, §3.1)."""
-    mb = block_tables.shape[1]
-    t = jnp.take(pool_leaf, block_tables, axis=1)  # [L, B, MB, BS, ...]
-    return t.reshape(t.shape[:2] + (mb * pool_leaf.shape[2],) + t.shape[4:])
+    t = to_logical(jnp.take(pool_leaf, block_tables, axis=1), p)  # [L, B, MB, BS, KV, d]
+    return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
 
 
-def scatter_block_token(pool_leaf, token_rows, block_ids, offsets):
-    """Append one token per decode row into its *tail block* in place.
+def scatter_block_tokens(pool_leaf, token_rows, block_ids, offsets, layer=None):
+    """Write T consecutive tokens per decode row into their blocks of a
+    stored pool leaf, in place.
 
-    ``pool_leaf`` [L, NB, BS, ...]; ``token_rows`` [L, B, ...] (the new
-    token's KV rows); ``block_ids``/``offsets`` [B] — per-row physical
-    block and in-block position of the write. Rows map to distinct live
-    blocks (shared prefix blocks are immutable and never a write
-    target), so the scatter is conflict-free; dead rows target the null
-    block."""
-    return pool_leaf.at[:, block_ids, offsets].set(token_rows)
+    ``pool_leaf`` [L, NB, KV, R, W]; ``token_rows`` [B, T, KV, d] for
+    one layer ``layer``, or [L, B, T, KV, d] for every layer (a scale
+    leaf's rows carry ``d = 1``); ``block_ids``/``offsets`` [B, T] —
+    each token's physical block and in-block position, for T
+    consecutive positions (``block_write_positions``), which may span a
+    block boundary (the scheduler pre-claims every tail block a verify
+    can reach via ``ensure_tail_n``).
+
+    Whole blocks move, never part of a tile: the blocks the T tokens
+    touch are read (``[KV, R, W]`` each, a run of whole (8, 128) tiles
+    where the leaf is tile-exact), the tokens put in, and the blocks
+    written back. A write of a token's ``[KV, d]`` alone would cut
+    across the tiles, and XLA would then either relayout the whole
+    stack around it or expand it into a loop of one update per token
+    and head. Live rows write exclusively-owned blocks; dead rows'
+    table entries all point at the null block, which is scratch: of
+    their colliding writes one block wins."""
+    NB, KV, R, W = pool_leaf.shape[1:]
+    d = token_rows.shape[-1]
+    p = W // d
+    bs = R * p
+    B, T = block_ids.shape
+    U = 1 + (T + bs - 2) // bs  # blocks T consecutive tokens can touch
+    if layer is None:
+        rows, layers = token_rows, jnp.arange(pool_leaf.shape[0])
+    else:
+        rows, layers = token_rows[None], jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    n_l = rows.shape[0]
+    off0 = offsets[:, :1]  # [B, 1]
+    reach = jnp.arange(U)[None, :] * bs - off0  # first token of each touched block
+    first = jnp.clip(reach, 0, T - 1)
+    blk = jnp.where(reach <= T - 1, jnp.take_along_axis(block_ids, first, axis=1), NB)
+    idx = jnp.stack(
+        [jnp.broadcast_to(layers[:, None, None], (n_l, B, U)),
+         jnp.broadcast_to(blk[None], (n_l, B, U))], axis=-1,
+    ).reshape(-1, 2).astype(jnp.int32)
+    mode = jax.lax.GatherScatterMode.FILL_OR_DROP  # index NB: no block
+    blocks = jax.lax.gather(
+        pool_leaf, idx,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(1, 2, 3), collapsed_slice_dims=(0, 1), start_index_map=(0, 1)
+        ),
+        slice_sizes=(1, 1, KV, R, W), mode=mode,
+    )
+    seq = to_logical(blocks.reshape(n_l, B, U, KV, R, W), p).reshape(n_l, B, U * bs, KV, d)
+    where = jnp.arange(U * bs)[None, :]
+    for t in range(T):
+        hit = (where == off0 + t)[None, :, :, None, None]
+        seq = jnp.where(hit, rows[:, :, t, None].astype(seq.dtype), seq)
+    new = to_stored(seq.reshape(n_l, B, U, bs, KV, d), p).reshape(-1, KV, R, W)
+    return jax.lax.scatter(
+        pool_leaf, idx, new,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2, 3),
+            inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1),
+        ),
+        mode=mode,
+    )
 
 
-def scatter_block_tokens(pool_leaf, token_rows, block_ids, offsets):
-    """Append T tokens per decode row into their tail blocks in place.
-
-    ``pool_leaf`` [L, NB, BS, ...]; ``token_rows`` [L, B, T, ...] (the
-    speculative-verify KV rows); ``block_ids``/``offsets`` [B, T] —
-    per-token physical block and in-block position (the T positions may
-    span a block boundary; the scheduler pre-claims every tail block
-    the verify can reach via ``ensure_tail_n``). Live rows write
-    exclusively-owned blocks; dead rows' table entries all point at the
-    null block, so their (possibly colliding) writes land in scratch."""
-    return pool_leaf.at[:, block_ids, offsets].set(token_rows)
-
-
-def _dense_as_pool(leaf, bs):
-    """A dense per-row cache leaf [B, Smax, ...] viewed as a block pool
-    [B·MB, BS, ...] — reshape only, no data movement — so the slot
-    layout routes through the same block-paged kernel as the paged one
-    (with the identity block table)."""
+def _dense_as_pool(leaf, bs, p, scale=False):
+    """A dense per-row cache leaf [B, Smax, KV(, hd)] as a one-layer
+    stored block pool [1, B·MB, KV, BS/p, p·hd] — the identity-table
+    view through which the slot layout runs the same block-paged kernel
+    as the paged one. Unlike the paged pool, which is kept stored, this
+    moves the cache's bytes: the slot layout pays a relayout per
+    call."""
+    if scale:
+        leaf = leaf[..., None]
     B, Smax = leaf.shape[0], leaf.shape[1]
-    return leaf.reshape((B * (Smax // bs), bs) + leaf.shape[2:])
+    return to_stored(leaf.reshape((B * (Smax // bs), bs) + leaf.shape[2:]), p)[None]
 
 
 def _dense_block_size(smax, bs=256):
@@ -271,49 +352,65 @@ def _kernel_cached_attention(q, k_cache, v_cache, cache_len, k_scale, v_scale, b
             f"identity-table view of the [B={B}, {Smax}, KV, hd] cache; "
             "choose a max_seq with a divisor >= 8, or backend='reference'"
         )
+    p = tokens_per_row(bs, hd)
     tables = jnp.arange(B * (Smax // bs), dtype=jnp.int32).reshape(B, Smax // bs)
     return kernel_ops.paged_attention(
         q,
-        _dense_as_pool(k_cache, bs),
-        _dense_as_pool(v_cache, bs),
+        _dense_as_pool(k_cache, bs, p),
+        _dense_as_pool(v_cache, bs, p),
         tables,
         cache_len,
-        None if k_scale is None else _dense_as_pool(k_scale, bs),
-        None if v_scale is None else _dense_as_pool(v_scale, bs),
+        None if k_scale is None else _dense_as_pool(k_scale, bs, p, scale=True),
+        None if v_scale is None else _dense_as_pool(v_scale, bs, p, scale=True),
         mode=backend,
     )
 
 
+def _dense_kv(q, k_pool, v_pool, block_tables, layer, k_scale, v_scale):
+    """Layer ``layer`` of stored pool leaves gathered into each row's
+    dense K/V [B, MB·BS, KV, hd] (int8 pools dequantized) — the
+    reference backend's read of the pool."""
+    p = k_pool.shape[-1] // q.shape[-1]
+
+    def rows(leaf):
+        one = jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=True)
+        return gather_block_rows(one, block_tables, p)[0]
+
+    kd, vd = rows(k_pool), rows(v_pool)
+    if k_scale is not None:
+        kd = dequantize_kv(kd, rows(k_scale)[..., 0], q.dtype)
+        vd = dequantize_kv(vd, rows(v_scale)[..., 0], q.dtype)
+    return kd, vd
+
+
 def paged_attention(
     q, k_pool, v_pool, block_tables, cache_len,
-    *, k_scale=None, v_scale=None, rules=None, backend=None,
+    *, layer=0, k_scale=None, v_scale=None, rules=None, backend=None,
 ):
     """Backend-dispatched paged decode/verify attention for one layer.
 
-    q [B,T,H,hd]; pools [NB,BS,KV,hd] (+ per-vector int8 scales);
-    ``block_tables`` [B,MB]; ``cache_len`` [B] committed lengths (the
-    new token rows are already scattered into the tail blocks; query t
-    attends positions < cache_len + t + 1). The kernel backends walk
-    the tables directly; the reference backend is the dense
-    ``gather_block_rows`` materialization — kept as the differential
-    oracle, no longer the serving hot path (DESIGN.md §4)."""
+    q [B,T,H,hd]; pools are the stored layer-stacked leaves
+    [L,NB,KV,R,W] (+ per-token int8 scales), of which layer ``layer`` is
+    read; ``block_tables`` [B,MB]; ``cache_len`` [B] committed lengths
+    (the new token rows are already scattered into the tail blocks;
+    query t attends positions < cache_len + t + 1). The kernel backends
+    walk the tables straight off the stack; the reference backend is
+    the dense ``gather_block_rows`` materialization — kept as the
+    differential oracle, no longer the serving hot path (DESIGN.md
+    §4)."""
     backend = kernel_ops.resolve_attention_backend(backend)
     if backend != "reference" and rules is None:
         return kernel_ops.paged_attention(
             q, k_pool, v_pool, block_tables, cache_len, k_scale, v_scale,
-            mode=backend,
+            layer=layer, mode=backend,
         )
-    kd = gather_block_rows(k_pool[None], block_tables)[0]  # [B, MB·BS, KV, hd]
-    vd = gather_block_rows(v_pool[None], block_tables)[0]
-    if k_scale is not None:
-        kd = dequantize_kv(kd, gather_block_rows(k_scale[None], block_tables)[0], q.dtype)
-        vd = dequantize_kv(vd, gather_block_rows(v_scale[None], block_tables)[0], q.dtype)
+    kd, vd = _dense_kv(q, k_pool, v_pool, block_tables, layer, k_scale, v_scale)
     return _cached_attention(q, kd, vd, cache_len, rules=rules, backend="reference")
 
 
 def paged_flash_partials(
     q, k_pool, v_pool, block_tables, cache_len, owned,
-    *, k_scale=None, v_scale=None, backend=None,
+    *, layer=0, k_scale=None, v_scale=None, backend=None,
 ):
     """Per-rank flash running-form partials for kv-sequence-split serving.
 
@@ -337,13 +434,9 @@ def paged_flash_partials(
     if kernel_ops.resolve_attention_backend(backend) != "reference":
         return kernel_ops.paged_attention_partials(
             q, k_pool, v_pool, block_tables, cache_len, owned,
-            k_scale, v_scale, mode=backend,
+            k_scale, v_scale, layer=layer, mode=backend,
         )
-    kd = gather_block_rows(k_pool[None], block_tables)[0]  # [B, MB·BS, KV, hd]
-    vd = gather_block_rows(v_pool[None], block_tables)[0]
-    if k_scale is not None:
-        kd = dequantize_kv(kd, gather_block_rows(k_scale[None], block_tables)[0], q.dtype)
-        vd = dequantize_kv(vd, gather_block_rows(v_scale[None], block_tables)[0], q.dtype)
+    kd, vd = _dense_kv(q, k_pool, v_pool, block_tables, layer, k_scale, v_scale)
     B, T, H, hd = q.shape
     S, KV = kd.shape[1], kd.shape[2]
     bs = S // block_tables.shape[1]
@@ -539,9 +632,10 @@ def attention_block(
     earlier-chunk KV + itself — same math as the ``prefix_kv`` branch,
     with the prefix read from the cache instead of concatenated.
     Paged decode/verify: cache is a dict {k, v[, k_scale, v_scale],
-    tables, li} of layer-stacked pool leaves [L,NB,BS,KV,hd] plus the
-    per-row block tables — the new token rows scatter into each row's
-    tail block and attention walks the tables (DESIGN.md §4).
+    tables, li} of the stored layer-stacked pool leaves [L,NB,KV,R,W]
+    plus the per-row block tables — the new token rows scatter into each
+    row's tail block of layer ``li`` and attention walks the tables
+    straight off the stack (DESIGN.md §4).
     ``backend`` picks the decode/verify attention backend (None → the
     ops registry default).
     """
@@ -591,7 +685,8 @@ def attention_block(
         # kernel backends (DESIGN.md §4). Dead rows' tables point at the
         # null block, so their writes land in scratch.
         tables, li = cache["tables"], cache["li"]
-        bs = cache["k"].shape[2]
+        R, W = cache["k"].shape[3:]
+        bs = R * (W // hd)
         T = k.shape[1]
         owned = None
         if seq_axis is not None:
@@ -610,27 +705,24 @@ def attention_block(
         if quant:
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
-            writes = (("k", k_q), ("k_scale", k_s), ("v", v_q), ("v_scale", v_s))
+            writes = (("k", k_q), ("k_scale", k_s[..., None]), ("v", v_q), ("v_scale", v_s[..., None]))
         else:
             writes = (("k", k), ("v", v))
+        # each new token is one [KV, d] window of layer li's stored
+        # block; the kernel then reads the stack itself at layer li
         stacks = {
-            name: cache[name].at[li, bid, off].set(val.astype(cache[name].dtype))
+            name: scatter_block_tokens(cache[name], val, bid, off, layer=li)
             for name, val in writes
         }
-        leaf = lambda name: jax.lax.dynamic_index_in_dim(
-            stacks[name], li, 0, keepdims=False
+        pools = dict(
+            layer=li,
+            k_scale=stacks["k_scale"] if quant else None,
+            v_scale=stacks["v_scale"] if quant else None,
         )
         if owned is None:
             out = paged_attention(
-                q,
-                leaf("k"),
-                leaf("v"),
-                tables,
-                cache_len,
-                k_scale=leaf("k_scale") if quant else None,
-                v_scale=leaf("v_scale") if quant else None,
-                rules=rules,
-                backend=backend,
+                q, stacks["k"], stacks["v"], tables, cache_len,
+                rules=rules, backend=backend, **pools,
             )
         else:
             # each rank attends over its owned positions only; the exact
@@ -638,15 +730,8 @@ def attention_block(
             # softmax across the seq mesh axis — rounding-level, not
             # bitwise (DESIGN.md §5)
             m_p, l_p, acc_p = paged_flash_partials(
-                q,
-                leaf("k"),
-                leaf("v"),
-                tables,
-                cache_len,
-                owned,
-                k_scale=leaf("k_scale") if quant else None,
-                v_scale=leaf("v_scale") if quant else None,
-                backend=backend,
+                q, stacks["k"], stacks["v"], tables, cache_len, owned,
+                backend=backend, **pools,
             )
             out = distributed_softmax(m_p, l_p, acc_p, seq_axis).astype(q.dtype)
         new_kv = tuple(stacks[name] for name, _ in writes)

@@ -348,10 +348,15 @@ class Model:
 
     # ------------------------------------------------------------------
     # decode
-    def jit_step(self, name: str, backend=None):
+    def jit_step(self, name: str, backend=None, donate=True):
         """``jax.jit`` of one decode-family step (``decode_step``,
         ``verify_step``, ``decode_step_paged``, ``verify_step_paged``)
         with the attention backend resolved and bound STATICALLY.
+        A paged step (``*_paged``) takes the pool as argument 1 and
+        hands back the pool with the new tokens written in: with
+        ``donate`` the program writes into the input buffer in place,
+        so the layer scan's carry is the pool itself and no second pool
+        is ever live.
         The single place backend binding happens (engine, scheduler,
         and draft streams all build their step fns here), so the
         registry is consulted before tracing — a later registry change
@@ -365,7 +370,8 @@ class Model:
         # the program's name in a profile: ``jit_serve_<name>``, not the
         # ``jit__unknown`` a functools.partial would give
         run.__name__ = f"serve_{name}"
-        return jax.jit(run)
+        paged = donate and name.endswith("_paged")
+        return jax.jit(run, donate_argnums=(1,) if paged else ())
 
     def paged_pool_specs(self, axis="model", seq_axis=None):
         """PartitionSpecs for the block-pool leaves under a serving mesh
@@ -381,7 +387,7 @@ class Model:
         )
 
     def sharded_paged_step(
-        self, name: str, mesh, backend=None, axis="model", seq_axis="seq"
+        self, name: str, mesh, backend=None, axis="model", seq_axis="seq", donate=True
     ):
         """``jit_step`` counterpart for mesh-sharded paged serving:
         ``jit(shard_map(...))`` of ``decode_step_paged`` /
@@ -414,13 +420,13 @@ class Model:
         the seq combine completes each head's softmax, then the head
         gather reassembles the full head set. Tables and lengths remain
         data, so the single-trace / no-retrace invariants of
-        ``jit_step`` carry over unchanged."""
+        ``jit_step`` carry over unchanged, and so does ``donate``."""
         backend = kernel_ops.resolve_attention_backend(backend, mesh=mesh)
         cfg = self.cfg
         tp = mesh.shape.get(axis, 1) if axis else 1
         sp = mesh.shape.get(seq_axis, 1) if seq_axis else 1
         if tp == 1 and sp == 1:
-            return self.jit_step(name, backend=backend)
+            return self.jit_step(name, backend=backend, donate=donate)
         if tp > 1 and (cfg.n_kv_heads % tp or cfg.n_heads % tp):
             raise ValueError(
                 f"n_kv_heads={cfg.n_kv_heads}/n_heads={cfg.n_heads} do not "
@@ -480,7 +486,7 @@ class Model:
             out_specs=(P(), pool_specs),
             check_vma=False,
         )
-        return jax.jit(fn)
+        return jax.jit(fn, donate_argnums=(1,) if donate else ())
 
     def init_cache(self, batch, max_seq, dtype=None):
         cfg = self.cfg
@@ -575,26 +581,40 @@ class Model:
     # block-paged decode cache (serve/kv_cache.PagedKVCache)
     def init_paged_cache(self, num_blocks, block_size, dtype=None):
         """Physical block pool: ``num_blocks`` blocks of ``block_size``
-        tokens each, laid out exactly like a decode cache with
-        batch=num_blocks and max_seq=block_size. Attention families only
-        — SSM state has no sequence axis to page. Per-row lengths live
-        with the block tables (PagedKVCache), not in the pool."""
+        tokens, each leaf stored layer-stacked as the paged kernel reads
+        it: K/V ``[L, NB, KV, BS/P, P·hd]`` and int8 scales ``[L, NB, KV,
+        BS/P, P]``, ``P = attention.tokens_per_row(BS, hd)`` (DESIGN.md
+        §4). Attention families only — SSM state has no sequence axis
+        to page. Per-row lengths live with the block tables
+        (PagedKVCache), not in the pool."""
         if self.cfg.family not in ATTN_FAMILIES:
             raise ValueError(
                 f"paged KV cache needs an attention family, got {self.cfg.family!r}"
             )
-        pool = self.init_cache(num_blocks, block_size, dtype=dtype)
-        pool.pop("len")
-        return pool
+        cfg = self.cfg
+        p = attn.tokens_per_row(block_size, cfg.head_dim)
+        blocks = (cfg.num_layers, num_blocks, cfg.n_kv_heads, block_size // p)
+        kv = blocks + (p * cfg.head_dim,)
+        if cfg.kv_quant:
+            return {
+                "k": jnp.zeros(kv, jnp.int8),
+                "v": jnp.zeros(kv, jnp.int8),
+                "k_scale": jnp.zeros(blocks + (p,), jnp.bfloat16),
+                "v_scale": jnp.zeros(blocks + (p,), jnp.bfloat16),
+            }
+        dt = dtype or _dtype(cfg)
+        return {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
 
     def paged_view(self, pool, block_tables):
-        """Dense [L, B, MB·BS, ...] per-row caches gathered from the block
-        pool through ``block_tables`` [B, MB] — the fixed-shape read side
-        of paged decode."""
-        return {
-            name: attn.gather_block_rows(leaf, block_tables)
-            for name, leaf in pool.items()
-        }
+        """Dense [L, B, MB·BS, ...] per-row caches gathered from the
+        stored block pool through ``block_tables`` [B, MB] — the
+        fixed-shape read side of the reference paged decode."""
+        p = pool["k"].shape[-1] // self.cfg.head_dim  # tokens a stored row
+        view = {}
+        for name, leaf in pool.items():
+            rows = attn.gather_block_rows(leaf, block_tables, p)
+            view[name] = rows[..., 0] if attn.is_scale(name) else rows
+        return view
 
     def decode_step_paged(
         self, params, pool, block_tables, cache_len, tokens, *, backend=None
@@ -627,20 +647,28 @@ class Model:
                 params, pool, block_tables, cache_len, tokens, backend
             )
             return logits[:, 0], new_pool
-        bs = pool["k"].shape[2]
         dense = self.paged_view(pool, block_tables)
         logits, new_dense = self.decode_step(
             params, dict(dense, len=cache_len), tokens, backend="reference"
         )
-        bid, off = attn.block_write_positions(block_tables, cache_len, 1, bs)
-        bid, off = bid[:, 0], off[:, 0]
+        return logits, self._write_back(pool, new_dense, block_tables, cache_len, 1)
+
+    def _write_back(self, pool, new_dense, block_tables, cache_len, t):
+        """Scatter the ``t`` token rows a reference step appended to its
+        dense view (at each row's ``cache_len``) back into the stored
+        pool through the tables — the reference route's write."""
+        bs = new_dense["k"].shape[2] // block_tables.shape[1]
+        bid, off = attn.block_write_positions(block_tables, cache_len, t, bs)
+        pos = cache_len[:, None] + jnp.arange(t)[None, :]  # [B, T]
         new_pool = {}
         for name, leaf in pool.items():
-            nd = new_dense[name]  # [L, B, MB·BS, ...]
-            idx = cache_len.reshape((1, -1, 1) + (1,) * (nd.ndim - 3))
-            token_rows = jnp.take_along_axis(nd, idx, axis=2)[:, :, 0]
-            new_pool[name] = attn.scatter_block_token(leaf, token_rows, bid, off)
-        return logits, new_pool
+            nd = new_dense[name]  # [L, B, MB·BS, KV(, hd)]
+            if attn.is_scale(name):
+                nd = nd[..., None]
+            idx = pos.reshape((1,) + pos.shape + (1,) * (nd.ndim - 3))
+            token_rows = jnp.take_along_axis(nd, idx, axis=2)  # [L, B, T, KV, d]
+            new_pool[name] = attn.scatter_block_tokens(leaf, token_rows, bid, off)
+        return new_pool
 
     def _step_paged_kernel(self, params, pool, block_tables, cache_len, tokens, backend):
         """Shared decode/verify layer scan over the block pool itself:
@@ -778,21 +806,12 @@ class Model:
             return self._step_paged_kernel(
                 params, pool, block_tables, cache_len, tokens, backend
             )
-        bs = pool["k"].shape[2]
-        T = tokens.shape[1]
         dense = self.paged_view(pool, block_tables)
         logits, new_dense = self.verify_step(
             params, dict(dense, len=cache_len), tokens, backend="reference"
         )
-        bid, off = attn.block_write_positions(block_tables, cache_len, T, bs)
-        pos = cache_len[:, None] + jnp.arange(T)[None, :]  # [B, T]
-        new_pool = {}
-        for name, leaf in pool.items():
-            nd = new_dense[name]  # [L, B, MB·BS, ...]
-            idx = pos.reshape((1,) + pos.shape + (1,) * (nd.ndim - 3))
-            token_rows = jnp.take_along_axis(nd, idx, axis=2)  # [L, B, T, ...]
-            new_pool[name] = attn.scatter_block_tokens(leaf, token_rows, bid, off)
-        return logits, new_pool
+        T = tokens.shape[1]
+        return logits, self._write_back(pool, new_dense, block_tables, cache_len, T)
 
     def decode_step(self, params, cache, tokens, *, backend=None):
         """tokens [B,1] → (logits [B,V], new cache). One new token.

@@ -180,22 +180,22 @@ def paged_pool_specs(
     *,
     quantized: bool = False,
 ) -> dict:
-    """PartitionSpecs for the paged KV pool leaves ``[L, NB, BS, KV, hd]``.
+    """PartitionSpecs for the stored paged KV pool leaves ``[L, NB, KV,
+    R, W]`` (``Model.init_paged_cache``).
 
     The serving mesh shards at most two pool dimensions: the kv-head dim
-    (3) over ``axis`` — PR 7's head-partitioned tensor parallelism,
+    (2) over ``axis`` — PR 7's head-partitioned tensor parallelism,
     bitwise-preserving — and the block dim (1) over ``seq_axis`` — the
     kv-sequence split, where each rank holds a contiguous range of
     physical blocks, attends over only the positions it owns, and the
     per-rank flash partials are combined by
     ``collectives.distributed_softmax`` (rounding-level, DESIGN.md §5).
-    Either axis may be ``None``; quantized pools carry per-(block, row)
-    scale leaves that shard the same way (minus the head_dim axis).
+    Either axis may be ``None``; quantized pools carry per-token scale
+    leaves ``[L, NB, KV, R, P]`` that shard the same way.
     """
-    kv = P(None, seq_axis, None, axis, None)
+    kv = P(None, seq_axis, axis, None, None)
     specs = {"k": kv, "v": kv}
     if quantized:
-        sc = P(None, seq_axis, None, axis)
-        specs["k_scale"] = sc
-        specs["v_scale"] = sc
+        specs["k_scale"] = kv
+        specs["v_scale"] = kv
     return specs

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kernel_ops
 from repro.serve.request import Request, ServeStats  # noqa: F401 (re-export)
-from repro.serve.scheduler import Scheduler
+from repro.serve.scheduler import Scheduler, paged_step
 from repro.serve.telemetry import TID_BACKEND, get_telemetry
 
 log = logging.getLogger("repro.serve")
@@ -136,6 +136,7 @@ class ServingEngine:
         self._steps: dict[str, dict] = {}  # backend → jitted decode/verify family
         self._plan_steps: dict = {}  # (plan key, pool size) → jitted plan step
         self._decode_plan = None
+        self._pool_recorded = False  # pool layout telemetry, once an engine
         if decode_plan is not None:
             self.set_decode_plan(decode_plan)
 
@@ -196,10 +197,8 @@ class ServingEngine:
     def _paged_fns(self, backend: str):
         fns = self._step_fns(backend)
         if "decode_paged" not in fns:
-            fns["decode_paged"] = (
-                self.model.sharded_paged_step("decode_step_paged", self.mesh, backend)
-                if self.mesh is not None
-                else self.model.jit_step("decode_step_paged", backend)
+            fns["decode_paged"] = paged_step(
+                self.model, "decode_step_paged", backend, self.mesh
             )
         if self._prefill_prefix is None:
             model, max_seq = self.model, self.max_seq
@@ -310,10 +309,8 @@ class ServingEngine:
         if "verify" not in fns:
             fns["verify"] = self.model.jit_step("verify_step", backend)
         if layout == "paged" and "verify_paged" not in fns:
-            fns["verify_paged"] = (
-                self.model.sharded_paged_step("verify_step_paged", self.mesh, backend)
-                if self.mesh is not None
-                else self.model.jit_step("verify_step_paged", backend)
+            fns["verify_paged"] = paged_step(
+                self.model, "verify_step_paged", backend, self.mesh
             )
         return fns["verify"], fns.get("verify_paged")
 
@@ -375,23 +372,24 @@ class ServingEngine:
                     block_size=self.block_size, num_blocks=self.num_blocks,
                     prefix_cache=False, mesh=self.mesh,
                 )
-                pool, tables, lens = kv.kernel_inputs()
+                _, tables, lens = kv.kernel_inputs()
                 decode_paged, _ = self._paged_fns(backend)
                 verify_paged = None
                 if any(k > 0 for k in ks):
                     _, verify_paged = self._spec_fns("paged", backend)
+
+                def paged(step, blk):
+                    # the steps take the pool donated: each call runs on
+                    # the pool the one before handed back
+                    logits, kv.pool = step(self.params, kv.pool, tables, lens, blk)
+                    return logits
+
                 for k in ks:
                     if k == 0:
-                        per_k[0] = _time(
-                            lambda: decode_paged(self.params, pool, tables, lens, tok)[0]
-                        )
+                        per_k[0] = _time(lambda: paged(decode_paged, tok))
                     else:
                         blk = jnp.zeros((mb, k + 1), jnp.int32)
-                        per_k[k] = _time(
-                            lambda blk=blk: verify_paged(
-                                self.params, pool, tables, lens, blk
-                            )[0]
-                        )
+                        per_k[k] = _time(lambda blk=blk: paged(verify_paged, blk))
             else:
                 cache = self.model.init_cache(mb, self.max_seq)
                 decode = self._step_fns(backend)["decode"]
@@ -517,7 +515,7 @@ class ServingEngine:
                 return out
 
             paged_kw.update(controller=controller, step_fn_resolver=_resolver)
-        return Scheduler(
+        sched = Scheduler(
             self.model,
             self.params,
             max_batch=max_batch,
@@ -536,6 +534,29 @@ class ServingEngine:
             telemetry=telemetry if telemetry is not None else self.telemetry,
             **paged_kw,
         )
+        if layout == "paged" and not self._pool_recorded and sched.tel.enabled:
+            self._record_pool(sched)
+        return sched
+
+    def _record_pool(self, sched) -> None:
+        """Once per engine, with telemetry on: how the paged pool is
+        stored — ``native_layout`` 1 when each stored head block is a
+        whole number of (8, 128) tiles, the shape the TPU keeps in the
+        layout the kernel reads in place (``attention.tokens_per_row``)
+        — and its bytes, as gauges and one instant on the backend
+        lane."""
+        pool = sched.kv.pool
+        rows, lanes = pool["k"].shape[3:]
+        native = int(rows % 8 == 0 and lanes % 128 == 0)
+        nbytes = sum(leaf.size * leaf.dtype.itemsize for leaf in pool.values())
+        reg = self.stats.registry
+        reg.gauge("pool.native_layout").set(native)
+        reg.gauge("pool.bytes").set(nbytes)
+        sched.tel.tracer.instant(
+            "pool-layout", "backend", tid=TID_BACKEND,
+            args={"native_layout": native, "pool_bytes": nbytes},
+        )
+        self._pool_recorded = True
 
     def serve(
         self,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import attention as attn
 from repro.models.model import PREFIX_FAMILIES
 from repro.serve.block import BlockAllocator, PrefixCache
 
@@ -55,81 +56,34 @@ def local_table_view(tables, nb_loc: int, rank):
     return local.astype(tables.dtype), owned
 
 
-# Pool blocks one install window spans: one 128-lane tile row of the TPU
-# layout XLA gives a pool leaf, whose block axis is the minor one.
-INSTALL_WINDOW = 128
-
-
-def _place_bits(payload, onehot):
-    """``out[:, c] = payload[:, j]`` for the ``j`` with ``onehot[j, c]``,
-    bit for bit, as a contraction over ``j`` on the matrix unit: each
-    byte of the payload's bits goes through as an int8 (byte − 128) with
-    int32 accumulation, so no value is rounded. Columns with no ``j``
-    hold junk. ``payload`` is ``[L, n, ...]``, ``onehot`` ``[n, W]``."""
-    width = payload.dtype.itemsize * 8
-    ut = {8: jnp.uint8, 16: jnp.uint16, 32: jnp.uint32}[width]
-    bits = jax.lax.bitcast_convert_type(payload, ut)
-    oh = onehot.astype(jnp.int8)
-    out = jnp.zeros((), ut)
-    for shift in range(0, width, 8):
-        byte = (((bits >> shift) & 0xFF).astype(jnp.int32) - 128).astype(jnp.int8)
-        got = jnp.einsum("lj...,jc->lc...", byte, oh, preferred_element_type=jnp.int32)
-        out = out | ((got + 128).astype(ut) << shift)
-    return jax.lax.bitcast_convert_type(out, payload.dtype)
-
-
-def _install_windows(leaf, payload, dst):
-    """``leaf[:, dst[j]] = payload[:, j]`` for every ``dst[j]`` inside the
-    leaf, one ``INSTALL_WINDOW``-block window at a time: read a window
-    that holds a ``dst``, place the payload's blocks into its columns,
-    write it back. The last window may overlap the one before it; a
-    block in both is written twice with the same bits."""
-    nb = leaf.shape[1]
-    w = min(INSTALL_WINDOW, nb)
-    n_win = -(-nb // w)
-    valid = (dst >= 0) & (dst < nb)
-    touched = jnp.zeros((n_win + 1,), bool).at[jnp.where(valid, dst // w, n_win)].set(True)
-    order = jnp.argsort(~touched[:n_win])  # touched windows first
-
-    def one_window(k, acc):
-        start = jnp.minimum(order[k] * w, nb - w)
-        col = dst - start
-        onehot = (valid & (col >= 0) & (col < w))[:, None] & (
-            col[:, None] == jnp.arange(w)[None, :]
-        )
-        keep = ~onehot.any(0).reshape((1, w) + (1,) * (acc.ndim - 2))
-        win = jax.lax.dynamic_slice_in_dim(acc, start, w, axis=1)
-        new = jnp.where(keep, win, _place_bits(payload, onehot))
-        return jax.lax.dynamic_update_slice_in_dim(acc, new, start, axis=1)
-
-    return jax.lax.fori_loop(0, jnp.sum(touched[:n_win]), one_window, leaf)
-
-
 def install_prefill_blocks(pool, dense, src, dst, *, seq_axis=None):
     """Copy blocks ``src`` of a batch=1 dense cache into pool slots
     ``dst`` (traced under ``PagedKVCache._install``, pool donated).
 
-    Dense leaves ``[L, 1, S, ...]`` are viewed in the pool's block layout
-    ``[L, S/BS, BS, ...]``; ``src``/``dst`` are int32 ``[n]``, and a
-    ``dst`` past the pool's last slot is a pad that writes nothing.
-
-    XLA keeps a pool leaf on the TPU with its block axis minor, so a
-    scatter of whole blocks relayouts the whole pool, twice, and a
-    per-block update writes one lane of every tile it touches. The
-    install works in windows of blocks instead (``_install_windows``);
-    the blocks a row claims together are mostly adjacent, so an install
-    touches a window or two. Under the kv-sequence split (``seq_axis``,
-    inside the pool's ``shard_map``) each rank installs the blocks of
-    its own shard."""
+    Dense leaves ``[L, 1, S, KV(, hd)]`` are cut into blocks ``[L, S/BS,
+    BS, KV(, hd)]``; the ``src`` blocks are taken and put in the pool's
+    stored form (``attention.to_stored``: a prompt-sized relayout, never
+    a pool-sized one), then scattered whole into the pool at ``dst``.
+    ``src``/``dst`` are int32 ``[n]``; a ``dst`` past the pool's last
+    slot is a pad that writes nothing. A stored leaf keeps its block
+    axis major (DESIGN.md §4), so each block written is one contiguous
+    run of the leaf per layer and the rest of the pool stays where it
+    is. Under the kv-sequence split (``seq_axis``, inside the pool's
+    ``shard_map``) each rank installs the blocks of its own shard."""
     out = {}
     for name, leaf in pool.items():
         d = dense[name]
+        if attn.is_scale(name):
+            d = d[..., None]
         L, _, S = d.shape[:3]
-        bs = leaf.shape[2]
-        blocks = d.reshape((L, S // bs, bs) + d.shape[3:])
-        payload = jnp.take(blocks, src, axis=1, mode="clip").astype(leaf.dtype)
-        local = dst if seq_axis is None else dst - jax.lax.axis_index(seq_axis) * leaf.shape[1]
-        out[name] = _install_windows(leaf, payload, local)
+        R, W = leaf.shape[3:]
+        p = W // d.shape[-1]
+        blocks = d.reshape((L, S // (R * p), R * p) + d.shape[3:])
+        payload = attn.to_stored(jnp.take(blocks, src, axis=1, mode="clip"), p)
+        nb = leaf.shape[1]
+        local = dst if seq_axis is None else dst - jax.lax.axis_index(seq_axis) * nb
+        local = jnp.where((local >= 0) & (local < nb), local, nb)  # the rest drop
+        out[name] = leaf.at[:, local].set(payload.astype(leaf.dtype), mode="drop")
     return out
 
 
@@ -334,7 +288,12 @@ class PagedKVCache:
                 name: jax.device_put(leaf, NamedSharding(mesh, specs[name]))
                 for name, leaf in self.pool.items()
             }
-            dense_specs = {name: P(spec[0], None, *spec[2:]) for name, spec in specs.items()}
+            head = "model" if tp > 1 else None
+            dense_specs = {
+                name: P(None, None, None, head) if attn.is_scale(name)
+                else P(None, None, None, head, None)
+                for name in specs
+            }
             install = shard_map(
                 functools.partial(install_prefill_blocks, seq_axis="seq" if sp > 1 else None),
                 mesh=mesh,
@@ -502,12 +461,13 @@ class PagedKVCache:
     def kernel_inputs(self):
         """The pool in the attention kernel's expected layout:
         ``(pool, block_tables, cache_len)`` with tables/lengths as
-        device int32 arrays. Pool leaves are layer-stacked
-        ``[L, NB+1, BS, ...]`` — block-major with ``block_size`` in the
-        sequence slot — which is exactly what
-        ``Model.decode_step_paged``/``verify_step_paged`` (and the
-        block-paged Pallas kernel underneath) consume; the extra block
-        is the null block dead rows write into.
+        device int32 arrays. Pool leaves are stored layer-stacked
+        ``[L, NB+1, KV, BS/P, P·hd]`` (``Model.init_paged_cache``), which
+        is exactly what ``Model.decode_step_paged``/``verify_step_paged``
+        (and the block-paged Pallas kernel underneath) consume; the
+        extra block is the null block dead rows write into. The steps
+        take the pool donated: the caller installs the pool they return
+        and never touches this one again.
 
         The device views are cached against mutation-version counters:
         block tables change only on admission / lazy tail claim /
@@ -528,8 +488,6 @@ class PagedKVCache:
         dense, for ``Model.prefill_with_prefix``. int8 pools dequantize
         here (per-vector scales live beside the values), so the suffix
         prefill always sees dense K/V whatever the cache dtype."""
-        from repro.models import attention as attn
-
         # pad the chain to blocks_per_row (repeating the last id) so the
         # gather runs at ONE fixed shape whatever the hit length — hit
         # lengths vary request to request, and a per-length eager
@@ -538,16 +496,12 @@ class PagedKVCache:
         h = len(hit_ids) * self.block_size
         ids = list(hit_ids) + [hit_ids[-1]] * (self.blocks_per_row - len(hit_ids))
         table = jnp.asarray(self._slot[np.array(ids, np.int32)][None, :])
-        k = attn.gather_block_rows(self.pool["k"], table)
-        v = attn.gather_block_rows(self.pool["v"], table)
+        view = self.model.paged_view(self.pool, table)
+        k, v = view["k"], view["v"]
         if self.model.cfg.kv_quant:
             dt = jnp.dtype(self.model.cfg.dtype)
-            k = attn.dequantize_kv(
-                k, attn.gather_block_rows(self.pool["k_scale"], table), dt
-            )
-            v = attn.dequantize_kv(
-                v, attn.gather_block_rows(self.pool["v_scale"], table), dt
-            )
+            k = attn.dequantize_kv(k, view["k_scale"], dt)
+            v = attn.dequantize_kv(v, view["v_scale"], dt)
         return np.asarray(k)[:, :, :h], np.asarray(v)[:, :, :h]
 
     def write_prefill(self, row: int, dense_cache, skip_blocks: int = 0) -> None:

@@ -83,6 +83,23 @@ from repro.serve.request import (
 from repro.serve.telemetry import TID_ADVISER, Telemetry, get_telemetry
 
 
+def paged_step(model, name: str, backend, mesh=None):
+    """The jitted paged decode-family step ``name`` for ``backend``:
+    ``Model.sharded_paged_step`` under a serving mesh, else
+    ``Model.jit_step``, the pool donated. The one exception is the
+    ``interpret`` backend, whose steps keep their input pool: the
+    benchmark's check plants a fault that hands a decode step's input
+    pool back as its output (``bench/tests/test_check.py``,
+    ``state_unchanged``), which must serve wrong tokens, not fail on a
+    deleted buffer. The exception goes once that fault hands back a
+    copy of the pool."""
+    backend = kernel_ops.resolve_attention_backend(backend, mesh=mesh)
+    donate = backend != "interpret"
+    if mesh is not None:
+        return model.sharded_paged_step(name, mesh, backend, donate=donate)
+    return model.jit_step(name, backend, donate=donate)
+
+
 @dataclass
 class _ChunkState:
     """An in-flight chunked prefill: the request's private batch-1 dense
@@ -218,7 +235,7 @@ class Scheduler:
         be = self.attention_backend
         self._decode = decode_fn or model.jit_step("decode_step", be)
         self._decode_paged = paged_decode_fn or (
-            model.jit_step("decode_step_paged", be) if kv_layout == "paged" else None
+            paged_step(model, "decode_step_paged", be) if kv_layout == "paged" else None
         )
         self._prefill_prefix = prefix_prefill_fn or (
             jax.jit(
@@ -258,7 +275,7 @@ class Scheduler:
             self._drafter.bind(max_batch, max_seq)
             self._verify = verify_fn or model.jit_step("verify_step", be)
             self._verify_paged = paged_verify_fn or (
-                model.jit_step("verify_step_paged", be) if kv_layout == "paged" else None
+                paged_step(model, "verify_step_paged", be) if kv_layout == "paged" else None
             )
         # live speculation depth: SpecConfig.k is the *maximum* (it sizes
         # the admission margin, the drafter overhang, and the deepest
@@ -358,11 +375,11 @@ class Scheduler:
             model = self.model
             fns = {"backend": backend, "decode": model.jit_step("decode_step", backend)}
             if self.kv_layout == "paged":
-                fns["decode_paged"] = model.jit_step("decode_step_paged", backend)
+                fns["decode_paged"] = paged_step(model, "decode_step_paged", backend)
             if self.spec is not None:
                 fns["verify"] = model.jit_step("verify_step", backend)
                 if self.kv_layout == "paged":
-                    fns["verify_paged"] = model.jit_step("verify_step_paged", backend)
+                    fns["verify_paged"] = paged_step(model, "verify_step_paged", backend)
             if self.chunk_size is not None:
                 fns["prefill_chunk"] = model.jit_step("prefill_chunk", backend)
             self._local_fns[backend] = fns
@@ -923,11 +940,12 @@ class Scheduler:
                 for row in self._active:
                     self.kv.ensure_tail(row)
                 pool, tables, lens = self.kv.kernel_inputs()
-            with self.tel.span("serve.decode", **self._decode_args()):
+            with self.tel.span("serve.decode", **self._decode_args()) as sp:
                 logits, new_pool = self._decode_paged(
                     self.params, pool, tables, lens, self._tok[:, None]
                 )
                 logits.block_until_ready()
+                self._note_donated(sp, pool)
             self.kv.pool = new_pool
             return logits
         with self.tel.span("serve.decode", **self._decode_args()):
@@ -942,6 +960,13 @@ class Scheduler:
             logits.block_until_ready()
         self.kv.cache = new_cache
         return logits
+
+    def _note_donated(self, span, pool) -> None:
+        """The span's ``donated`` arg: 1 when the step consumed its input
+        pool (every leaf deleted by donation), read only when the tracer
+        is on."""
+        if self._ton:
+            span.note(donated=int(all(leaf.is_deleted() for leaf in jax.tree.leaves(pool))))
 
     def _decode_args(self) -> dict:
         """The decode span's args, read from host state only when the
@@ -983,11 +1008,12 @@ class Scheduler:
                 for row in self._active:
                     self.kv.ensure_tail_n(row, K + 1)
                 pool, tables, lens = self.kv.kernel_inputs()
-            with self.tel.span("serve.verify", k=K):
+            with self.tel.span("serve.verify", k=K) as sp:
                 logits, new_pool = self._verify_paged(
                     self.params, pool, tables, lens, tokens_in
                 )
                 logits.block_until_ready()
+                self._note_donated(sp, pool)
             self.kv.pool = new_pool
         else:
             with self.tel.span("serve.verify", k=K):

@@ -177,18 +177,33 @@ print("PASS")
 """)
 
 
-def test_seq_split_logit_tolerance_empty_shards():
+def _layout(layout: str, block_size: int):
+    """(head_dim, block_size) of a stored pool layout: ``fallback`` keeps
+    the test's config (head_dim 8: one token of a head per stored row)
+    and block size; ``tile`` stores two tokens to a 128-lane row
+    (head_dim 64, block 16), the tile-exact form smollm-135m serves."""
+    return (8, block_size) if layout == "fallback" else (64, 16)
+
+
+@pytest.mark.parametrize("layout", ["fallback", "tile"])
+def test_seq_split_logit_tolerance_empty_shards(layout):
     """Tolerance-lane logit bound with the empty-shard guard on the hot
     path: rows short enough that one rank's kv-sequence shard holds ZERO
     blocks still decode NaN-free, argmax-identical, and within the
-    rounding bound of the single-device step."""
-    _run(_mesh_header((2,), ("seq",)) + """
+    rounding bound of the single-device step — the kernel's partials
+    mode on both stored pool layouts."""
+    hd, bs = _layout(layout, 8)
+    tile = (bs // 2, 128) if layout == "tile" else (bs, hd)  # a stored head block
+    _run(_mesh_header((2,), ("seq",)) + f"""
 from repro.serve.differential import assert_logits_close
 from repro.serve.kv_cache import PagedKVCache
-m, params = build(CFG)
+BS = {bs}
+m, params = build(dataclasses.replace(CFG, head_dim={hd}))
+assert m.init_paged_cache(2, BS)["k"].shape[3:] == {tile}
+""" + """
 prompts = [(np.arange(3, dtype=np.int32) * (i + 1)) % cfg_vocab for i in range(4)]
 def one_step(use_mesh):
-    kv = PagedKVCache(m, max_batch=4, max_seq=32, block_size=8,
+    kv = PagedKVCache(m, max_batch=4, max_seq=32, block_size=BS,
                       mesh=mesh if use_mesh else None, prefix_cache=False)
     for i, p in enumerate(prompts):
         assert kv.try_admit(i, p, budget=8) is not None
@@ -208,22 +223,28 @@ print("PASS")
 """)
 
 
+@pytest.mark.parametrize("layout", ["fallback", "tile"])
 @pytest.mark.parametrize(
     "shape,names", [((2,), ("model",)), ((2,), ("seq",))], ids=["model2", "seq2"]
 )
-def test_sharded_install_keeps_pool_sharding(shape, names):
+def test_sharded_install_keeps_pool_sharding(shape, names, layout):
     """``write_prefill`` on a head-split or seq-split pool returns every
     leaf on the sharding it had (the install donates the pool, pinned to
     its shardings), and the rows it wrote, gathered through each pool's
-    slot map, equal the unsharded install's."""
-    _run(_mesh_header(shape, names) + """
+    slot map, equal the unsharded install's, on both stored pool
+    layouts."""
+    hd, bs = _layout(layout, 4)
+    _run(_mesh_header(shape, names) + f"""
+BS = {bs}
+CFG = dataclasses.replace(CFG, head_dim={hd})
+""" + """
 from repro.serve.kv_cache import PagedKVCache
 m, params = build(CFG)
 prompts = [(np.arange(n, dtype=np.int32) * 3 + 1) % cfg_vocab for n in (5, 8, 13)]
 prefill = jax.jit(lambda pr: m.prefill(params, pr, 32))
 dense = [prefill(jnp.asarray(p)[None])[1] for p in prompts]
 def install(use_mesh):
-    kv = PagedKVCache(m, max_batch=4, max_seq=32, block_size=4,
+    kv = PagedKVCache(m, max_batch=4, max_seq=32, block_size=BS,
                       mesh=mesh if use_mesh else None)
     before = {name: leaf.sharding for name, leaf in kv.pool.items()}
     assert not use_mesh or any(a is not None for a in before["k"].spec)
